@@ -1,10 +1,9 @@
 """Kernel micro-benchmarks.
 
-On this CPU container the Pallas kernels run in interpret mode (Python
-per-block execution — correctness, not speed), so the timed artifact is
-the pure-jnp reference path plus an analytic bytes/FLOPs model per kernel;
-on a TPU runtime set REPRO_PALLAS_COMPILED=1 and the same harness times
-the compiled kernels.
+Off the TPU the Pallas kernels run in interpret mode (Python per-block
+execution — correctness, not speed), so the timed artifact there is the
+pure-jnp reference path plus an analytic bytes/FLOPs model per kernel;
+on a TPU backend the kernels compile and the same harness times them.
 """
 from __future__ import annotations
 

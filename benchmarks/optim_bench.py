@@ -185,7 +185,10 @@ def sharded_lm_row():
     parameter storage on a 4 data x 2 model host-CPU mesh — what
     ``--arch lm-* --optimizer nghf`` runs per step, θ-sized CG state
     sharded included.  The child process times the settled (warm-started,
-    donating) step and prints the JSON row; the parent re-emits it."""
+    donating) step and prints the JSON row; the parent re-emits it.  The
+    child is forced onto 8 host-CPU devices (the parent may hold the
+    accelerator), so the row records the child's own platform: it is a
+    CPU number, never a chip one."""
     import subprocess
     import sys
     import textwrap
@@ -227,7 +230,8 @@ def sharded_lm_row():
         us = (time.perf_counter() - t0) / iters * 1e6
         print(json.dumps({
             "bench": "optim_update", "optimizer": "nghf_fsdp4x2",
-            "mesh": "4x2", "devices": int(jax.device_count()),
+            "mesh": "4x2", "device": jax.devices()[0].platform,
+            "devices": int(jax.device_count()),
             "param_sharding": "2d", "warm_start": True,
             "B": 8, "cg_B": 4, "T": 32,
             "ms_per_update": round(us / 1e3, 4),

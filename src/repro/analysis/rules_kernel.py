@@ -58,6 +58,8 @@ def _iter_grid_points(grid: Tuple[int, ...]):
 def _check_one_spec(name: str, what: str, spec, shape: Tuple[int, ...],
                     grid: Tuple[int, ...]) -> List[str]:
     out: List[str] = []
+    if spec.block_shape is None:                   # whole operand, e.g. SMEM
+        return out
     bs = tuple(spec.block_shape)
     if len(bs) != len(shape):
         return [f"KS001: {name} {what}: block_shape {bs} rank "
@@ -170,14 +172,11 @@ def check_frontier_invariants(lat, fr) -> List[str]:
 # kernel name -> [(operand position, operand name, bounds fn)] where the
 # bounds fn maps the launch's operand shape list to (lo, hi_exclusive):
 # the half-open range every element of that index operand must lie in.
-# Sentinel conventions are encoded here: level_arcs uses -1 for padding
-# (guarded by `maximum(., 0)` + a mask in-kernel), the frontier position
-# tensors use the dump slot L*W as their largest legal value.
+# The frontier position tensors use the dump slot L*W as their largest
+# legal value; the bound comes from the (B, L, W) tile at operand 0.  The
+# arc-layout gathers (level_arcs, frame endpoints) run in XLA, outside
+# every kernel.
 GATHER_SPECS: Dict[str, List[Tuple[int, str, Callable]]] = {
-    "_loss_only_kernel": [
-        (1, "idx", lambda shp: (0, shp[0][1])),          # into cumext
-        (3, "level_arcs", lambda shp: (-1, shp[2][2])),  # into (B,3,A)
-    ],
     "_dag_fwd_kernel": [
         (5, "pidx", lambda shp: (0, shp[0][1] * shp[0][2] + 1)),
     ],
@@ -185,9 +184,7 @@ GATHER_SPECS: Dict[str, List[Tuple[int, str, Callable]]] = {
         (4, "sidx", lambda shp: (0, shp[0][1] * shp[0][2] + 1)),
     ],
     "_dag_loss_only_kernel": [
-        (1, "idx", lambda shp: (0, shp[0][1])),
-        (3, "level_arcs", lambda shp: (-1, shp[2][2])),
-        (4, "pidx", lambda shp: (0, shp[3][1] * shp[3][2] + 1)),
+        (5, "pidx", lambda shp: (0, shp[0][1] * shp[0][2] + 1)),
     ],
 }
 

@@ -12,15 +12,17 @@ the dot product rides along for free — a 1.4x traffic cut on the CG
 stage's vector phase (the matrix-free products dominate FLOPs, but on
 θ = 72 B parameters these AXPYs move ~1 TB/update unfused).
 
-Design: 1-D grid over VMEM-sized tiles of the flattened vectors; the rr
-partial sums land in a per-tile output reduced by the caller (exact f32
-tree reduction, deterministic).
+Design: 1-D grid over VMEM-sized tiles of the flattened vectors; the
+scalar ``alpha`` is read from SMEM and the per-tile rr partial sums land
+in an SMEM vector reduced by the caller (exact f32 tree reduction,
+deterministic).
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import instrument
 from repro.kernels.dispatch import resolve_interpret
@@ -37,7 +39,7 @@ def _cg_kernel(alpha_ref, x_ref, v_ref, r_ref, bv_ref,
     r_new = r - alpha * bv
     x_out_ref[...] = x_new.astype(x_out_ref.dtype)
     r_out_ref[...] = r_new.astype(r_out_ref.dtype)
-    rr_ref[0] = jnp.sum(r_new * r_new)
+    rr_ref[pl.program_id(0)] = jnp.sum(r_new * r_new)
 
 
 def cg_fused_update(alpha, x, v, r, bv, *, block: int = 65536,
@@ -45,7 +47,7 @@ def cg_fused_update(alpha, x, v, r, bv, *, block: int = 65536,
     """Flat f32/bf16 arrays (N,) -> (x_new, r_new, rr scalar).
 
     ``interpret=None`` auto-detects via ``kernels.dispatch``: compiled on
-    TPU (or ``REPRO_PALLAS_COMPILED=1``), interpreter elsewhere."""
+    TPU, interpreter elsewhere."""
     (N,) = x.shape
     pad = (-N) % block
     if pad:
@@ -57,7 +59,7 @@ def cg_fused_update(alpha, x, v, r, bv, *, block: int = 65536,
         _cg_kernel,
         grid=(n_blocks,),
         in_specs=[
-            pl.BlockSpec((1,), lambda i: (0,)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((block,), lambda i: (i,)),
             pl.BlockSpec((block,), lambda i: (i,)),
             pl.BlockSpec((block,), lambda i: (i,)),
@@ -66,7 +68,7 @@ def cg_fused_update(alpha, x, v, r, bv, *, block: int = 65536,
         out_specs=[
             pl.BlockSpec((block,), lambda i: (i,)),
             pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((1,), lambda i: (i,)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((N + pad,), x.dtype),

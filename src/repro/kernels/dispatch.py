@@ -2,32 +2,25 @@
 
 Every kernel wrapper in this package takes ``interpret: bool | None``
 and resolves it here: compiled where the kernel actually lowers (a TPU
-default backend, or ``REPRO_PALLAS_COMPILED=1`` to force it, e.g. under
-the TPU-backed CI lane), the Pallas interpreter everywhere else (CPU CI
-containers).  ``cg_fused``'s ``use_pallas=None`` auto-dispatch keys off
-the same predicate — interpret-mode Pallas would only add per-block
-overhead where XLA already fuses the pure-jnp reference.
+default backend), the Pallas interpreter everywhere else (CPU test
+runs).  ``cg_fused``'s ``use_pallas=None`` auto-dispatch keys off the
+same predicate — interpret-mode Pallas would only add per-block overhead
+where XLA already fuses the pure-jnp reference.
 
-Historically ``ops._interpret`` (env var only) and
-``lattice_fb._auto_interpret`` (env var + backend) disagreed: on a real
-TPU without the env var, ``swa_attention`` ran in interpret mode while
-the lattice kernels compiled.  Keeping the predicate in one place is
-what the kernel sanitizer (``repro.analysis.sanitize_kernels``) audits
-against.
+Keeping the predicate in one place is what the kernel sanitizer
+(``repro.analysis.sanitize_kernels``) audits against, and what the TPU
+compile tests (``tests/test_tpu_compile.py``) steer to lower the kernels
+for a described chip from a CPU-only process.
 """
 from __future__ import annotations
-
-import os
 
 import jax
 
 
 def compiled_backend() -> bool:
     """True when Pallas kernels should lower for real instead of running
-    in the interpreter: TPU default backend, or forced via
-    ``REPRO_PALLAS_COMPILED=1``."""
-    return (os.environ.get("REPRO_PALLAS_COMPILED", "0") == "1"
-            or jax.default_backend() == "tpu")
+    in the interpreter: the default JAX backend is a TPU."""
+    return jax.default_backend() == "tpu"
 
 
 def resolve_interpret(interpret: bool | None) -> bool:

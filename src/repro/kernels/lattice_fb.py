@@ -1,21 +1,19 @@
 """Pallas TPU kernels: lattice forward AND backward passes + expected
-correctness (confusion-network / sausage topology).
+correctness, for confusion-network (sausage) and general-DAG lattices.
 
 This is the TPU-native backend of the levelized lattice engine
 (``repro.lattice_engine``), the compute hot-spot of the paper's
 "collecting statistics over lattices" stage (Table 1).  The engine owns
 backend dispatch: the general-DAG per-arc scan and the level-parallel scan
 live in ``repro/lattice_engine/{scan_backend,levelized}.py``; these kernels
-are the specialisation for sausage lattices (every arc of segment s
-connects to every arc of segment s-1 — the synthetic generator's topology,
-and the dominant topology of pruned confusion networks).  The engine
-gathers arc tensors into the (segments, alternatives) layout via
-``Lattice.level_arcs`` and wraps the pair of kernels in a
-``jax.custom_jvp`` so that ``jax.grad`` / ``jax.jvp`` flow through them
-via the closed-form occupancy identities (see
-``lattice_engine/pallas_backend.py``).
+run the same recursions with the per-level state in VMEM.  The engine
+gathers arc tensors into the (levels, width) layout via
+``Lattice.level_arcs`` and wraps the kernels in a ``jax.custom_jvp`` so
+that ``jax.grad`` / ``jax.jvp`` flow through them via the closed-form
+occupancy identities (see ``lattice_engine/pallas_backend.py``).
 
-Forward recursion (per utterance, sequential over segments s):
+Sausage forward recursion (per utterance, sequential over segments s;
+every arc of segment s connects to every arc of segment s-1):
 
     in_log(s)   = logsumexp(alpha[s-1])
     alpha[s,a]  = score[s,a] + in_log(s)
@@ -27,48 +25,45 @@ Backward recursion (sequential over segments in reverse):
     beta[s,a]   = logsumexp_a'(score[s+1,a'] + beta[s+1,a'])   (0 at final)
     c_beta[s,a] = sum softmax(score[s+1]+beta[s+1]) * (corr[s+1]+c_beta[s+1])
 
-Both kernels honour an arc ``mask`` (B,S,A): masked arcs score -inf and
-contribute nothing; a fully-masked segment (arc-count padding from
-``make_sausage_lattice(max_arcs=...)`` or batch-level levelization padding)
-passes the carry through unchanged, so ``logZ``/``c_avg`` are exact for
-ragged batches.
+Both sausage kernels honour an arc ``mask`` (B,S,A): masked arcs score
+-inf and contribute nothing; a fully-masked segment (arc-count padding
+from ``make_sausage_lattice(max_arcs=...)`` or batch-level levelization
+padding) passes the carry through unchanged, so ``logZ``/``c_avg`` are
+exact for ragged batches.
 
-A third, *fused loss-only* kernel (``sausage_loss_only``) serves the CG
-stage's candidate evaluation (paper Alg. 1 — ~73 % of CG wall time in
-Table 1): it takes the mean-centred log-prob cumsum grid (one batched
-streaming O(T*K) pass over the frame log-probabilities, the same
-identity as ``lattice_engine.common.arc_scores``) plus the ARC-LAYOUT
-lattice fields, and — inside the kernel — gathers the 2A span endpoints
-into per-arc scores, gathers arcs into the (segments, alternatives)
-layout via ``level_arcs``, and runs only the forward recursion, emitting
-just ``(logZ, c_avg)``.  No (B, A) or (B, S, A) score tensors are
-materialised, no alpha/c_alpha tiles are written, and no backward pass
-runs: the candidate-eval graph is one streaming pass over the log-probs
-plus one kernel whose intermediates stay VMEM-resident instead of
-round-tripping (B, S, A) statistics through HBM.
+The *loss-only* kernels (``sausage_loss_only`` / ``dag_loss_only``) serve
+the CG stage's candidate evaluation (paper Alg. 1 — ~73 % of CG wall time
+in Table 1): the wrapper builds the per-arc scores from the frame
+log-probs with the mean-centred cumsum endpoint gather
+(``ref.sausage_arc_scores_ref``, the same identity as
+``lattice_engine.common.arc_scores``) and gathers them into the
+(levels, width) layout; the kernel runs only the forward recursion and
+emits just ``(logZ, c_avg)`` — no alpha/c_alpha tiles leave VMEM and no
+backward pass runs.
 
-TPU mapping of the fused kernel: BATCH-BLOCKED — one kernel invocation
-holds the whole (B, (T+1)K) cumsum grid plus the packed (B, 4, A) arc
-fields in VMEM (≈300 KB at the paper-scale shapes, far under the 16 MB
-budget), does two combined vector gathers (endpoints, arc->sausage), and
-runs the segment recursion on (B, W) frontier rows with the carries in
-registers.  Batching the grid into the block (instead of gridding over
-utterances like the kernel pair) keeps the gathers wide and amortises
-the per-step control overhead; gridding over batch *chunks* when the
-cumsum tile outgrows VMEM is future work alongside the general-DAG
-kernel.  The arbitrary-index gathers are exercised in interpreter mode
-everywhere except real TPU backends (same ``interpret`` auto-detection
-as the kernel pair; compiled-mode TPU validation is a ROADMAP item).
+TPU mapping: every kernel grids over the batch, one utterance per grid
+step, with its (L, W) level tiles in VMEM (a few KB per utterance at the
+paper-scale shapes: L = T/4 levels of W = 3 alternatives).  The
+sequential level recursion runs inside the kernel and reads/writes one
+level row at a time through the refs (``ref[pl.ds(l, 1), :]``).  The
+scalar carries are (1, 1) tiles and ``logZ``/``c_avg`` leave as (B, 1, 1)
+arrays (a block's last two dims must be (8, 128)-aligned or whole).  The
+frame-level endpoint gather stays in XLA: Mosaic has no arbitrary-index
+lane gather, and a per-utterance grid would otherwise DMA the whole
+(T+1, K) cumsum grid (3 MB at T=128, K=6000) to read 3 values per arc.
 
-TPU mapping: grid over the batch; per-utterance (S, A) score/corr/mask
-tiles in VMEM; the sequential segment recursion runs inside the kernel
-with the running carries in registers/VMEM scratch — the HBM->VMEM traffic
-is one pass over the scores, vs. one gather per arc in the scan-based
-general path.
+The general-DAG kernels keep a level-major position buffer (one column of
+L*W+1 rows, the last the dump slot) in VMEM scratch.  A level's
+predecessor (successor) gather is a one-hot select-and-sum over that
+column — exact in f32, since exactly one position matches — and its
+write-back is the transposed select.  This is O(L*W) work per gathered
+slot: cheap at the frontier sizes the trainer and the service produce,
+and what a TPU core can run without a lane gather.
 
-``interpret`` defaults to auto-detection: compiled on TPU backends,
-interpreter everywhere else (CPU CI containers).  Validated against
-ref.sausage_forward_ref / ref.sausage_backward_ref.
+``interpret`` defaults to auto-detection (``kernels.dispatch``): compiled
+on TPU backends, interpreter everywhere else (CPU CI containers).
+Validated against the oracles in ``kernels/ref.py``; ``tests/
+test_tpu_compile.py`` compiles every kernel for a described TPU v5e.
 """
 from __future__ import annotations
 
@@ -77,78 +72,126 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401  (TPU lowering)
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import instrument
 from repro.kernels.dispatch import resolve_interpret
+from repro.kernels.ref import gather_sausage_ref, sausage_arc_scores_ref
 
 NEG = -1e30
 _EPS = 1e-30
+_F32 = jnp.float32
+
+
+def _row(ref, i):
+    """Row ``i`` (traced) of a (rows, lanes) VMEM ref, as (1, lanes)."""
+    return ref[pl.ds(i, 1), :]
+
+
+def _utt_spec(*dims):
+    """Per-utterance block over a leading batch axis: (B, *dims) arrays,
+    one whole utterance per grid step."""
+    return pl.BlockSpec((None,) + dims, lambda b: (b,) + (0,) * len(dims))
+
+
+def _scalar_out(B):
+    """(B, 1, 1) per-utterance scalar output (logZ / c_avg)."""
+    return _utt_spec(1, 1), jax.ShapeDtypeStruct((B, 1, 1), _F32)
+
+
+# ---------------------------------------------------------------------------
+# Sausage kernels: fully-connected segment recursion
+# ---------------------------------------------------------------------------
+
+
+def _sausage_recursion(score_ref, corr_ref, mask_ref, alpha_ref, calpha_ref,
+                       num_segments: int):
+    """Forward segment recursion of one utterance; writes alpha/c_alpha
+    rows when the refs are given and returns the (1, 1) (logZ, c_avg)."""
+
+    def seg_step(s, carry):
+        in_log, c_in = carry                                  # (1, 1)
+        m = _row(mask_ref, s)                                 # (1, A)
+        valid = m > 0.5
+        seg_valid = jnp.max(m, axis=1, keepdims=True) > 0.5
+        row = jnp.where(valid, _row(score_ref, s) + in_log, NEG)
+        c_row = jnp.where(valid, _row(corr_ref, s) + c_in, 0.0)
+        if alpha_ref is not None:
+            alpha_ref[pl.ds(s, 1), :] = row
+            calpha_ref[pl.ds(s, 1), :] = c_row
+        mx = jnp.max(row, axis=1, keepdims=True)
+        e = jnp.exp(row - mx) * m
+        z = jnp.sum(e, axis=1, keepdims=True)
+        new_in_log = jnp.where(seg_valid, jnp.log(jnp.maximum(z, _EPS)) + mx,
+                               in_log)
+        w = e / jnp.maximum(z, _EPS)
+        new_c_in = jnp.where(seg_valid,
+                             jnp.sum(w * c_row, axis=1, keepdims=True), c_in)
+        return new_in_log, new_c_in
+
+    zero = jnp.zeros((1, 1), _F32)
+    return jax.lax.fori_loop(0, num_segments, seg_step, (zero, zero))
 
 
 def _fwd_kernel(score_ref, corr_ref, mask_ref, alpha_ref, calpha_ref,
                 logz_ref, cavg_ref, *, num_segments: int):
-    score = score_ref[...].astype(jnp.float32)      # (S, A)
-    corr = corr_ref[...].astype(jnp.float32)
-    mask = mask_ref[...].astype(jnp.float32)
+    logz_ref[...], cavg_ref[...] = _sausage_recursion(
+        score_ref, corr_ref, mask_ref, alpha_ref, calpha_ref, num_segments)
 
-    def seg_step(s, carry):
-        in_log, c_in = carry
-        m = mask[s]
-        valid = m > 0.5
-        seg_valid = jnp.max(m) > 0.5
-        row = jnp.where(valid, score[s] + in_log, NEG)
-        c_row = jnp.where(valid, corr[s] + c_in, 0.0)
-        alpha_ref[s, :] = row
-        calpha_ref[s, :] = c_row
-        mx = row.max()
-        e = jnp.exp(row - mx) * m
-        z = e.sum()
-        new_in_log = jnp.where(seg_valid, jnp.log(jnp.maximum(z, _EPS)) + mx,
-                               in_log)
-        w = e / jnp.maximum(z, _EPS)
-        new_c_in = jnp.where(seg_valid, jnp.sum(w * c_row), c_in)
-        return new_in_log, new_c_in
 
-    in_log, c_in = jax.lax.fori_loop(
-        0, num_segments, seg_step, (jnp.float32(0.0), jnp.float32(0.0)))
-    logz_ref[0] = in_log
-    cavg_ref[0] = c_in
+def _loss_only_kernel(score_ref, corr_ref, mask_ref, logz_ref, cavg_ref, *,
+                      num_segments: int):
+    """Forward-only segment recursion: only (logZ, c_avg) leave."""
+    logz_ref[...], cavg_ref[...] = _sausage_recursion(
+        score_ref, corr_ref, mask_ref, None, None, num_segments)
 
 
 def _bwd_kernel(score_ref, corr_ref, mask_ref, beta_ref, cbeta_ref,
                 *, num_segments: int):
-    score = score_ref[...].astype(jnp.float32)      # (S, A)
-    corr = corr_ref[...].astype(jnp.float32)
-    mask = mask_ref[...].astype(jnp.float32)
 
     def seg_step(i, carry):
-        out_log, c_out = carry
+        out_log, c_out = carry                                # (1, 1)
         s = num_segments - 1 - i
-        m = mask[s]
+        m = _row(mask_ref, s)
         valid = m > 0.5
-        seg_valid = jnp.max(m) > 0.5
+        seg_valid = jnp.max(m, axis=1, keepdims=True) > 0.5
         b_row = jnp.where(valid, out_log, NEG)
         cb_row = jnp.where(valid, c_out, 0.0)
-        beta_ref[s, :] = b_row
-        cbeta_ref[s, :] = cb_row
-        row = jnp.where(valid, score[s] + b_row, NEG)
-        mx = row.max()
+        beta_ref[pl.ds(s, 1), :] = b_row
+        cbeta_ref[pl.ds(s, 1), :] = cb_row
+        row = jnp.where(valid, _row(score_ref, s) + b_row, NEG)
+        mx = jnp.max(row, axis=1, keepdims=True)
         e = jnp.exp(row - mx) * m
-        z = e.sum()
+        z = jnp.sum(e, axis=1, keepdims=True)
         new_out_log = jnp.where(seg_valid,
                                 jnp.log(jnp.maximum(z, _EPS)) + mx, out_log)
         w = e / jnp.maximum(z, _EPS)
-        new_c_out = jnp.where(seg_valid, jnp.sum(w * (corr[s] + cb_row)),
-                              c_out)
+        new_c_out = jnp.where(
+            seg_valid,
+            jnp.sum(w * (_row(corr_ref, s) + cb_row), axis=1, keepdims=True),
+            c_out)
         return new_out_log, new_c_out
 
-    jax.lax.fori_loop(0, num_segments, seg_step,
-                      (jnp.float32(0.0), jnp.float32(0.0)))
+    zero = jnp.zeros((1, 1), _F32)
+    jax.lax.fori_loop(0, num_segments, seg_step, (zero, zero))
 
 
-def _ones_mask(scores):
-    return jnp.ones(scores.shape, jnp.float32)
+def _sausage_call(kernel, outs, scores, corr, mask, interpret):
+    """Launch one sausage kernel over (B, S, A) f32 tiles; ``outs`` lists
+    the output kinds, "tile" (B, S, A) or "scalar" (B, 1, 1)."""
+    B, S, A = scores.shape
+    if mask is None:
+        mask = jnp.ones(scores.shape, _F32)
+    tile = (_utt_spec(S, A), jax.ShapeDtypeStruct((B, S, A), _F32))
+    specs = [tile if o == "tile" else _scalar_out(B) for o in outs]
+    return instrument.pallas_call(
+        functools.partial(kernel, num_segments=S),
+        grid=(B,),
+        in_specs=[_utt_spec(S, A)] * 3,
+        out_specs=[s for s, _ in specs],
+        out_shape=[o for _, o in specs],
+        interpret=resolve_interpret(interpret),
+    )(scores.astype(_F32), corr.astype(_F32), mask.astype(_F32))
 
 
 def sausage_forward(scores, corr, mask=None, *, interpret: bool | None = None):
@@ -157,263 +200,224 @@ def sausage_forward(scores, corr, mask=None, *, interpret: bool | None = None):
 
     Returns (alpha (B,S,A), c_alpha (B,S,A), logZ (B,), c_avg (B,)).
     """
-    B, S, A = scores.shape
-    if mask is None:
-        mask = _ones_mask(scores)
-    kernel = functools.partial(_fwd_kernel, num_segments=S)
-    alpha, c_alpha, logz, cavg = instrument.pallas_call(
-        kernel,
-        grid=(B,),
-        in_specs=[
-            pl.BlockSpec((None, S, A), lambda b: (b, 0, 0)),
-            pl.BlockSpec((None, S, A), lambda b: (b, 0, 0)),
-            pl.BlockSpec((None, S, A), lambda b: (b, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((None, S, A), lambda b: (b, 0, 0)),
-            pl.BlockSpec((None, S, A), lambda b: (b, 0, 0)),
-            pl.BlockSpec((None, 1), lambda b: (b, 0)),
-            pl.BlockSpec((None, 1), lambda b: (b, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, S, A), jnp.float32),
-            jax.ShapeDtypeStruct((B, S, A), jnp.float32),
-            jax.ShapeDtypeStruct((B, 1), jnp.float32),
-            jax.ShapeDtypeStruct((B, 1), jnp.float32),
-        ],
-        interpret=resolve_interpret(interpret),
-    )(scores, corr, mask.astype(jnp.float32))
-    return alpha, c_alpha, logz[:, 0], cavg[:, 0]
+    alpha, c_alpha, logz, cavg = _sausage_call(
+        _fwd_kernel, ("tile", "tile", "scalar", "scalar"), scores, corr,
+        mask, interpret)
+    return alpha, c_alpha, logz[:, 0, 0], cavg[:, 0, 0]
 
 
-def _loss_only_kernel(cum_ref, idx_ref, fcs_ref, level_ref, logz_ref,
-                      cavg_ref, *, num_segments: int, num_arcs: int):
-    """Fused candidate-evaluation kernel, batch-blocked: arc scores
-    (ONE combined endpoint gather on the centred cumsum grid), the
-    arc->sausage gather (one more), and the forward-only recursion all
-    live in the kernel; only the (B,) outputs are written.
+def sausage_backward(scores, corr, mask=None, *,
+                     interpret: bool | None = None):
+    """Backward (beta / c_beta) companion of :func:`sausage_forward`.
 
-    cum:   (B, (T+1)*K + K) centred cumsum grid flattened per utterance,
-           PRE-SCALED by kappa, with the (scaled) per-state means appended
-           as a trailing pseudo-row (one streaming O(T*K) pass over the
-           log-probs, done outside — see ``sausage_loss_only``; scaling
-           the grid is exactly scaling the acoustic score, so kappa never
-           needs to be a kernel constant and may be traced).
-    idx:   (B, 3*A) int32 — [end*K+label | start*K+label | mean-row+label]
-           gather positions into ``cum``.
-    fcs:   (B, 4, A) f32 — packed [span, lm, corr, arc_mask] arc fields.
-    level: (B, S, W) int32 level_arcs frontier map (-1 padded).
+    Returns (beta (B,S,A), c_beta (B,S,A)); beta excludes the arc's own
+    score (FBStats convention), so gamma = exp(alpha + beta - logZ).
     """
-    cum = cum_ref[...]
-    g = jnp.take_along_axis(cum, idx_ref[...], axis=1)         # (B, 3A)
-    A = num_arcs
-    fcs = fcs_ref[...]
-    # centred partial sums stay O(sqrt(T)) so short-span endpoint
-    # differences don't cancel catastrophically at large T; the removed
-    # linear ramp is restored exactly from span * mu[label]
-    score_arc = (g[:, :A] - g[:, A:2 * A]
-                 + fcs[:, 0] * g[:, 2 * A:]) + fcs[:, 1]
-    la = level_ref[...]                                        # (B, S, W)
-    B, S, W = la.shape
-    safe = jnp.maximum(la, 0).reshape(B, 1, S * W)
-    stacked = jnp.stack([score_arc, fcs[:, 2], fcs[:, 3]], axis=1)
-    gath = jnp.take_along_axis(stacked, safe, axis=2).reshape(B, 3, S, W)
-    score, corr = gath[:, 0], gath[:, 1]
-    mask = jnp.where(la >= 0, gath[:, 2], 0.0)
+    beta, c_beta = _sausage_call(_bwd_kernel, ("tile", "tile"), scores,
+                                 corr, mask, interpret)
+    return beta, c_beta
 
-    # the segment loop is the plain forward kernel's, batched over B —
-    # minus its per-step alpha/c_alpha VMEM writes
-    def seg_step(s, carry):
-        in_log, c_in = carry                                   # (B,)
-        m = mask[:, s]
-        valid = m > 0.5
-        seg_valid = jnp.max(m, axis=1) > 0.5
-        row = jnp.where(valid, score[:, s] + in_log[:, None], NEG)
-        c_row = jnp.where(valid, corr[:, s] + c_in[:, None], 0.0)
-        mx = row.max(axis=1)
-        e = jnp.exp(row - mx[:, None]) * m
-        z = e.sum(axis=1)
-        new_in_log = jnp.where(seg_valid,
-                               jnp.log(jnp.maximum(z, _EPS)) + mx, in_log)
-        w = e / jnp.maximum(z, _EPS)[:, None]
-        new_c_in = jnp.where(seg_valid, jnp.sum(w * c_row, axis=1), c_in)
-        return new_in_log, new_c_in
 
-    in_log, c_in = jax.lax.fori_loop(
-        0, num_segments, seg_step,
-        (jnp.zeros((B,), jnp.float32), jnp.zeros((B,), jnp.float32)))
-    logz_ref[...] = in_log
-    cavg_ref[...] = c_in
+def _level_arc_scores(log_probs, start, end, label, lm, kappa):
+    """(B, A) acoustic+lm arc scores from the (B, T, K) frame log-probs
+    (the mean-centred cumsum endpoint gather, in XLA)."""
+    return sausage_arc_scores_ref(log_probs, start, end, label, kappa) \
+        + lm.astype(_F32)
 
 
 def sausage_loss_only(log_probs, start, end, label, lm, corr, arc_mask,
                       level_arcs, *, kappa: float = 1.0,
                       interpret: bool | None = None):
-    """Fused loss-only forward: (logZ (B,), c_avg (B,)) straight from the
-    frame log-probs and ARC-LAYOUT lattice fields.
+    """Loss-only forward: (logZ (B,), c_avg (B,)) straight from the frame
+    log-probs and ARC-LAYOUT lattice fields.
 
     log_probs: (B, T, K) frame log-probabilities; start/end/label:
     (B, A) int32 arc span endpoints and output units (pad arcs may hold
     any in-range index — ``arc_mask`` must zero them); lm/corr/arc_mask:
-    (B, A); level_arcs: (B, S, W) int32 frontier map (-1 padded) — the
-    arc->sausage gather happens inside the kernel.  ``kappa`` is the
-    acoustic scale; it is folded into the cumsum grid (a linear map), so
-    a traced/jitted kappa works like on the other backends.
+    (B, A); level_arcs: (B, S, W) int32 frontier map (-1 padded).
+    ``kappa`` is the acoustic scale and may be traced.
 
     Not differentiable directly (Pallas calls have no autodiff rules) —
     ``lattice_engine.pallas_backend`` wraps it in a ``custom_jvp``.
     """
-    B, T, K = log_probs.shape
-    A = start.shape[1]
-    S, W = level_arcs.shape[1], level_arcs.shape[2]
-    # mean-centred cumsum grid, ONE batched streaming pass over the
-    # log-probs; the per-state means ride along as a trailing pseudo-row
-    # so the kernel's single combined gather also fetches mu[label], and
-    # kappa is folded in here (the score is linear in the grid).
-    # Centring keeps short-span endpoint differences accurate at large T;
-    # see common.arc_scores.
-    lp = log_probs.astype(jnp.float32)
-    mu = jnp.mean(lp, axis=1)                                  # (B, K)
-    cum = jnp.cumsum(lp - mu[:, None, :], axis=1)
-    cum = jnp.concatenate([jnp.zeros_like(cum[:, :1]), cum], axis=1)
-    cumext = jnp.concatenate([cum.reshape(B, -1), mu], axis=1) * kappa
-    # gather positions + packed per-arc float fields (cheap int/stack ops;
-    # everything downstream happens inside the kernel)
-    lab = label.astype(jnp.int32)
-    idx = jnp.concatenate(
-        [end.astype(jnp.int32) * K + lab, start.astype(jnp.int32) * K + lab,
-         (T + 1) * K + lab], axis=1)                           # (B, 3A)
-    span = (end - start).astype(jnp.float32)
-    fcs = jnp.stack([span, lm.astype(jnp.float32), corr.astype(jnp.float32),
-                     arc_mask.astype(jnp.float32)], axis=1)    # (B, 4, A)
-    kernel = functools.partial(_loss_only_kernel, num_segments=S,
-                               num_arcs=A)
-    logz, cavg = instrument.pallas_call(
-        kernel,
-        out_shape=[
-            jax.ShapeDtypeStruct((B,), jnp.float32),
-            jax.ShapeDtypeStruct((B,), jnp.float32),
-        ],
-        interpret=resolve_interpret(interpret),
-    )(cumext, idx, fcs, level_arcs.astype(jnp.int32))
-    return logz, cavg
+    score_arc = _level_arc_scores(log_probs, start, end, label, lm, kappa)
+    logz, cavg = _sausage_call(
+        _loss_only_kernel, ("scalar", "scalar"),
+        gather_sausage_ref(score_arc, level_arcs, 0.0),
+        gather_sausage_ref(corr.astype(_F32), level_arcs, 0.0),
+        gather_sausage_ref(arc_mask.astype(_F32), level_arcs, 0.0),
+        interpret)
+    return logz[:, 0, 0], cavg[:, 0, 0]
 
 
 # ---------------------------------------------------------------------------
 # General-DAG kernels: level-frontier recursion over the levelized tensors
 # (losses.lattice.lattice_frontiers).  Same recursions as the levelized
 # scan backend, but the per-level gathers, the masked logsumexp/softmax
-# reductions and the level-major alpha/beta buffers all live in VMEM
-# inside one kernel invocation per utterance — no per-level HLO dispatch,
-# no (L*W+1,) buffer round-trips through HBM.  Unlike the sausage pair,
+# reductions and the level-major position buffers all live in VMEM
+# inside one kernel invocation per utterance.  Unlike the sausage pair,
 # final arcs may sit on ANY level, so logZ/c_avg are reduced over the
 # final-flag mask at the end instead of from the last segment's carry.
 # ---------------------------------------------------------------------------
 
 
-def _masked_lse_rows(x, axis=-1):
-    """In-kernel masked logsumexp + masked-softmax weights over ``axis``
-    (entries at/near NEG are masked; all-masked rows -> exactly NEG and
-    all-zero weights) — the kernel-side twin of ``ref._masked_lse_row``."""
-    valid = x > NEG * 0.5
-    m = jnp.max(x, axis=axis)
-    m0 = jnp.where(m > NEG * 0.5, m, 0.0)
-    e = jnp.where(valid, jnp.exp(x - jnp.expand_dims(m0, axis)), 0.0)
-    z = jnp.sum(e, axis=axis)
-    has = jnp.any(valid, axis=axis)
-    lse = jnp.where(has,
-                    jnp.maximum(jnp.log(jnp.maximum(z, _EPS)) + m0, NEG),
+def _buffer_rows(L: int, W: int) -> int:
+    """Rows of the level-major position buffer: L*W slots + the dump slot
+    at L*W, rounded up to whole (8, 128) tiles."""
+    return -(-(L * W + 1) // 8) * 8
+
+
+def _masked_lse(xs):
+    """Masked logsumexp over a list of equally shaped tiles (entries
+    at/near NEG are masked; all-masked positions -> exactly NEG and
+    all-zero weights) — the kernel-side twin of ``ref._masked_lse_row``
+    with the reduced axis unrolled.  Returns (lse, [weights])."""
+    m = functools.reduce(jnp.maximum, xs)
+    has = m > NEG * 0.5
+    m0 = jnp.where(has, m, 0.0)
+    es = [jnp.where(x > NEG * 0.5, jnp.exp(x - m0), 0.0) for x in xs]
+    z = functools.reduce(jnp.add, es)
+    lse = jnp.where(has, jnp.maximum(jnp.log(jnp.maximum(z, _EPS)) + m0, NEG),
                     NEG)
-    w = e / jnp.expand_dims(jnp.maximum(z, _EPS), axis)
-    return lse, w
+    zs = jnp.maximum(z, _EPS)
+    return lse, [e / zs for e in es]
+
+
+def _masked_lse_tile(x):
+    """Masked logsumexp over a whole (L, W) tile -> (1, 1), with the
+    (L, W) masked-softmax weights."""
+    def full(op, t):
+        return op(op(t, axis=1, keepdims=True), axis=0, keepdims=True)
+    m = full(jnp.max, x)
+    has = m > NEG * 0.5
+    m0 = jnp.where(has, m, 0.0)
+    e = jnp.where(x > NEG * 0.5, jnp.exp(x - m0), 0.0)
+    z = full(jnp.sum, e)
+    lse = jnp.where(has, jnp.maximum(jnp.log(jnp.maximum(z, _EPS)) + m0, NEG),
+                    NEG)
+    return lse, e / jnp.maximum(z, _EPS)
+
+
+def _gather_rows(buf, idx):
+    """buf: (N, 1) position column; idx: (1, W) positions -> (1, W)
+    values buf[idx] (one-hot select-and-sum; exact, one match)."""
+    pos = jax.lax.broadcasted_iota(jnp.int32, (buf.shape[0], idx.shape[1]), 0)
+    return jnp.sum(jnp.where(pos == idx, buf, 0.0), axis=0, keepdims=True)
+
+
+def _scatter_level(buf, vals, l, W):
+    """Write the (1, W) row ``vals`` into positions l*W .. l*W+W-1 of the
+    (N, 1) position column ``buf``."""
+    N = buf.shape[0]
+    pos = jax.lax.broadcasted_iota(jnp.int32, (N, W), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (N, W), 1)
+    hit = pos == l * W + lane
+    placed = jnp.sum(jnp.where(hit, vals, 0.0), axis=1, keepdims=True)
+    col = jax.lax.broadcasted_iota(jnp.int32, (N, 1), 0)
+    in_level = (col >= l * W) & (col < l * W + W)
+    return jnp.where(in_level, placed, buf)
 
 
 def _dag_fwd_kernel(own_ref, corr_ref, start_ref, ok_ref, final_ref,
                     pidx_ref, alpha_ref, calpha_ref, logz_ref, cavg_ref,
-                    *, num_levels: int, width: int):
-    own = own_ref[...].astype(jnp.float32)          # (L, W)
-    corr = corr_ref[...].astype(jnp.float32)
-    start = start_ref[...] > 0.5
-    ok = ok_ref[...] > 0.5
-    pidx = pidx_ref[...]                            # (L, W, P)
-    L, W = num_levels, width
-    LW = L * W
+                    abuf_ref, cbuf_ref):
+    """Forward frontier recursion of one utterance: alpha/c_alpha (L, W)
+    level by level through the level-major position buffers, then
+    logZ / c_avg over the final arcs, which may sit on any level."""
+    P, L, W = pidx_ref.shape
+    abuf_ref[...] = jnp.full(abuf_ref.shape, NEG, _F32)
+    cbuf_ref[...] = jnp.zeros(cbuf_ref.shape, _F32)
 
     def level_step(l, carry):
-        a_buf, c_buf = carry                        # (LW+1,)
-        pidx_l = jax.lax.dynamic_index_in_dim(pidx, l, 0, keepdims=False)
-        pa = a_buf[pidx_l]                          # (W, P)
-        pc = c_buf[pidx_l]
-        in_log, w = _masked_lse_rows(pa)
-        c_in = jnp.sum(w * pc, axis=-1)
-        own_l = jax.lax.dynamic_index_in_dim(own, l, 0, keepdims=False)
-        corr_l = jax.lax.dynamic_index_in_dim(corr, l, 0, keepdims=False)
-        start_l = jax.lax.dynamic_index_in_dim(start, l, 0, keepdims=False)
-        ok_l = jax.lax.dynamic_index_in_dim(ok, l, 0, keepdims=False)
+        a_col, c_col = abuf_ref[...], cbuf_ref[...]           # (N, 1)
+        idx = [pidx_ref[p, pl.ds(l, 1), :] for p in range(P)]  # (1, W)
+        in_log, ws = _masked_lse([_gather_rows(a_col, i) for i in idx])
+        c_in = functools.reduce(jnp.add, [
+            w * _gather_rows(c_col, i) for w, i in zip(ws, idx)])
+        own_l = _row(own_ref, l)
+        start_l = _row(start_ref, l) > 0.5
+        ok_l = _row(ok_ref, l) > 0.5
         a_val = jnp.where(start_l, own_l, own_l + in_log)
-        c_val = corr_l + jnp.where(start_l, 0.0, c_in)
+        c_val = _row(corr_ref, l) + jnp.where(start_l, 0.0, c_in)
         a_val = jnp.where(ok_l, a_val, NEG)
         c_val = jnp.where(ok_l, c_val, 0.0)
-        a_buf = jax.lax.dynamic_update_slice(a_buf, a_val, (l * W,))
-        c_buf = jax.lax.dynamic_update_slice(c_buf, c_val, (l * W,))
-        return a_buf, c_buf
+        alpha_ref[pl.ds(l, 1), :] = a_val
+        calpha_ref[pl.ds(l, 1), :] = c_val
+        abuf_ref[...] = _scatter_level(a_col, a_val, l, W)
+        cbuf_ref[...] = _scatter_level(c_col, c_val, l, W)
+        return carry
 
-    a_buf, c_buf = jax.lax.fori_loop(
-        0, L, level_step,
-        (jnp.full((LW + 1,), NEG, jnp.float32),
-         jnp.zeros((LW + 1,), jnp.float32)))
-    alpha_ref[...] = a_buf[:LW].reshape(L, W)
-    calpha_ref[...] = c_buf[:LW].reshape(L, W)
-    # final-arc reduction: finals may live on any level in a general DAG
-    fin = final_ref[...].reshape(-1) > 0.5          # (LW,)
-    af = jnp.where(fin, a_buf[:LW], NEG)
-    logz, w = _masked_lse_rows(af)
-    logz_ref[0] = logz
-    cavg_ref[0] = jnp.sum(w * c_buf[:LW])
+    jax.lax.fori_loop(0, L, level_step, 0)
+    af = jnp.where(final_ref[...] > 0.5, alpha_ref[...], NEG)
+    logz, w = _masked_lse_tile(af)
+    logz_ref[...] = logz
+    cavg_ref[...] = jnp.sum(jnp.sum(w * calpha_ref[...], axis=1,
+                                    keepdims=True), axis=0, keepdims=True)
+
+
+def _dag_loss_only_kernel(own_ref, corr_ref, start_ref, ok_ref, final_ref,
+                          pidx_ref, logz_ref, cavg_ref, alpha_ref,
+                          calpha_ref, abuf_ref, cbuf_ref):
+    """Forward-only frontier recursion: alpha/c_alpha stay in VMEM
+    scratch; only (logZ, c_avg) leave."""
+    _dag_fwd_kernel(own_ref, corr_ref, start_ref, ok_ref, final_ref,
+                    pidx_ref, alpha_ref, calpha_ref, logz_ref, cavg_ref,
+                    abuf_ref, cbuf_ref)
 
 
 def _dag_bwd_kernel(own_ref, corr_ref, final_ref, ok_ref, sidx_ref,
-                    beta_ref, cbeta_ref, *, num_levels: int, width: int):
-    own = own_ref[...].astype(jnp.float32)          # (L, W)
-    corr = corr_ref[...].astype(jnp.float32)
-    final = final_ref[...] > 0.5
-    ok = ok_ref[...] > 0.5
-    sidx = sidx_ref[...]                            # (L, W, S)
-    L, W = num_levels, width
-    LW = L * W
-    okf = ok.reshape(-1)
-    own_pad = jnp.concatenate(
-        [jnp.where(okf, own.reshape(-1), NEG),
-         jnp.full((1,), NEG, jnp.float32)])         # (LW+1,)
-    corr_pad = jnp.concatenate(
-        [jnp.where(okf, corr.reshape(-1), 0.0),
-         jnp.zeros((1,), jnp.float32)])
+                    beta_ref, cbeta_ref, qbuf_ref, cqbuf_ref):
+    """Backward frontier recursion.  The position buffers hold what a
+    successor gather needs, q = beta + own and cq = c_beta + corr (NEG and
+    0 at empty slots and at the dump slot)."""
+    S, L, W = sidx_ref.shape
+    qbuf_ref[...] = jnp.full(qbuf_ref.shape, NEG, _F32)
+    cqbuf_ref[...] = jnp.zeros(cqbuf_ref.shape, _F32)
 
     def level_step(i, carry):
-        b_buf, cb_buf = carry                       # (LW+1,)
         l = L - 1 - i
-        sidx_l = jax.lax.dynamic_index_in_dim(sidx, l, 0, keepdims=False)
-        s_out = jnp.where(sidx_l < LW, b_buf[sidx_l] + own_pad[sidx_l],
-                          NEG)                      # (W, S)
-        sc = cb_buf[sidx_l] + corr_pad[sidx_l]
-        out_log, w = _masked_lse_rows(s_out)
-        c_out = jnp.sum(w * sc, axis=-1)
-        final_l = jax.lax.dynamic_index_in_dim(final, l, 0, keepdims=False)
-        ok_l = jax.lax.dynamic_index_in_dim(ok, l, 0, keepdims=False)
-        b_val = jnp.where(final_l, 0.0, out_log)
-        c_val = jnp.where(final_l, 0.0, c_out)
-        b_val = jnp.where(ok_l, b_val, NEG)
-        c_val = jnp.where(ok_l, c_val, 0.0)
-        b_buf = jax.lax.dynamic_update_slice(b_buf, b_val, (l * W,))
-        cb_buf = jax.lax.dynamic_update_slice(cb_buf, c_val, (l * W,))
-        return b_buf, cb_buf
+        q_col, cq_col = qbuf_ref[...], cqbuf_ref[...]         # (N, 1)
+        idx = [sidx_ref[s, pl.ds(l, 1), :] for s in range(S)]  # (1, W)
+        out_log, ws = _masked_lse([_gather_rows(q_col, j) for j in idx])
+        c_out = functools.reduce(jnp.add, [
+            w * _gather_rows(cq_col, j) for w, j in zip(ws, idx)])
+        final_l = _row(final_ref, l) > 0.5
+        ok_l = _row(ok_ref, l) > 0.5
+        b_val = jnp.where(ok_l, jnp.where(final_l, 0.0, out_log), NEG)
+        c_val = jnp.where(ok_l, jnp.where(final_l, 0.0, c_out), 0.0)
+        beta_ref[pl.ds(l, 1), :] = b_val
+        cbeta_ref[pl.ds(l, 1), :] = c_val
+        q = jnp.where(ok_l, b_val + _row(own_ref, l), NEG)
+        cq = jnp.where(ok_l, c_val + _row(corr_ref, l), 0.0)
+        qbuf_ref[...] = _scatter_level(q_col, q, l, W)
+        cqbuf_ref[...] = _scatter_level(cq_col, cq, l, W)
+        return carry
 
-    b_buf, cb_buf = jax.lax.fori_loop(
-        0, L, level_step,
-        (jnp.full((LW + 1,), NEG, jnp.float32),
-         jnp.zeros((LW + 1,), jnp.float32)))
-    beta_ref[...] = b_buf[:LW].reshape(L, W)
-    cbeta_ref[...] = cb_buf[:LW].reshape(L, W)
+    jax.lax.fori_loop(0, L, level_step, 0)
+
+
+def _dag_call(kernel, outs, tiles, frontier, interpret, scratch_tiles=0):
+    """Launch one DAG kernel: ``tiles`` are (B, L, W) level-major operands,
+    ``frontier`` the (B, L, W, F) predecessor/successor positions (laid
+    out (B, F, L, W) for the kernel).  ``outs`` as in ``_sausage_call``;
+    ``scratch_tiles`` extra (L, W) VMEM tiles precede the two position
+    buffers."""
+    B, L, W = tiles[0].shape
+    F = frontier.shape[-1]
+    tile = (_utt_spec(L, W), jax.ShapeDtypeStruct((B, L, W), _F32))
+    specs = [tile if o == "tile" else _scalar_out(B) for o in outs]
+    N = _buffer_rows(L, W)
+    return instrument.pallas_call(
+        kernel,
+        grid=(B,),
+        in_specs=[_utt_spec(L, W)] * len(tiles) + [_utt_spec(F, L, W)],
+        out_specs=[s for s, _ in specs],
+        out_shape=[o for _, o in specs],
+        scratch_shapes=([pltpu.VMEM((L, W), _F32)] * scratch_tiles
+                        + [pltpu.VMEM((N, 1), _F32)] * 2),
+        interpret=resolve_interpret(interpret),
+    )(*[t.astype(_F32) for t in tiles],
+      jnp.moveaxis(frontier.astype(jnp.int32), -1, 1))
 
 
 def dag_forward(own, corr, start, ok, final, pidx, *,
@@ -429,37 +433,10 @@ def dag_forward(own, corr, start, ok, final, pidx, *,
     Returns (alpha (B,L,W), c_alpha (B,L,W), logZ (B,), c_avg (B,)).
     Validated against ``ref.dag_forward_ref``.
     """
-    B, L, W = own.shape
-    P = pidx.shape[-1]
-    kernel = functools.partial(_dag_fwd_kernel, num_levels=L, width=W)
-    alpha, c_alpha, logz, cavg = instrument.pallas_call(
-        kernel,
-        grid=(B,),
-        in_specs=[
-            pl.BlockSpec((None, L, W), lambda b: (b, 0, 0)),
-            pl.BlockSpec((None, L, W), lambda b: (b, 0, 0)),
-            pl.BlockSpec((None, L, W), lambda b: (b, 0, 0)),
-            pl.BlockSpec((None, L, W), lambda b: (b, 0, 0)),
-            pl.BlockSpec((None, L, W), lambda b: (b, 0, 0)),
-            pl.BlockSpec((None, L, W, P), lambda b: (b, 0, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((None, L, W), lambda b: (b, 0, 0)),
-            pl.BlockSpec((None, L, W), lambda b: (b, 0, 0)),
-            pl.BlockSpec((None, 1), lambda b: (b, 0)),
-            pl.BlockSpec((None, 1), lambda b: (b, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, L, W), jnp.float32),
-            jax.ShapeDtypeStruct((B, L, W), jnp.float32),
-            jax.ShapeDtypeStruct((B, 1), jnp.float32),
-            jax.ShapeDtypeStruct((B, 1), jnp.float32),
-        ],
-        interpret=resolve_interpret(interpret),
-    )(own.astype(jnp.float32), corr.astype(jnp.float32),
-      start.astype(jnp.float32), ok.astype(jnp.float32),
-      final.astype(jnp.float32), pidx.astype(jnp.int32))
-    return alpha, c_alpha, logz[:, 0], cavg[:, 0]
+    alpha, c_alpha, logz, cavg = _dag_call(
+        _dag_fwd_kernel, ("tile", "tile", "scalar", "scalar"),
+        (own, corr, start, ok, final), pidx, interpret)
+    return alpha, c_alpha, logz[:, 0, 0], cavg[:, 0, 0]
 
 
 def dag_backward(own, corr, final, ok, sidx, *,
@@ -468,106 +445,19 @@ def dag_backward(own, corr, final, ok, sidx, *,
     successor frontier positions ``sidx`` (B, L, W, S).  beta excludes the
     arc's own score (FBStats convention).  Validated against
     ``ref.dag_backward_ref``."""
-    B, L, W = own.shape
-    S = sidx.shape[-1]
-    kernel = functools.partial(_dag_bwd_kernel, num_levels=L, width=W)
-    beta, c_beta = instrument.pallas_call(
-        kernel,
-        grid=(B,),
-        in_specs=[
-            pl.BlockSpec((None, L, W), lambda b: (b, 0, 0)),
-            pl.BlockSpec((None, L, W), lambda b: (b, 0, 0)),
-            pl.BlockSpec((None, L, W), lambda b: (b, 0, 0)),
-            pl.BlockSpec((None, L, W), lambda b: (b, 0, 0)),
-            pl.BlockSpec((None, L, W, S), lambda b: (b, 0, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((None, L, W), lambda b: (b, 0, 0)),
-            pl.BlockSpec((None, L, W), lambda b: (b, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, L, W), jnp.float32),
-            jax.ShapeDtypeStruct((B, L, W), jnp.float32),
-        ],
-        interpret=resolve_interpret(interpret),
-    )(own.astype(jnp.float32), corr.astype(jnp.float32),
-      final.astype(jnp.float32), ok.astype(jnp.float32),
-      sidx.astype(jnp.int32))
+    beta, c_beta = _dag_call(_dag_bwd_kernel, ("tile", "tile"),
+                             (own, corr, final, ok), sidx, interpret)
     return beta, c_beta
-
-
-def _dag_loss_only_kernel(cum_ref, idx_ref, fcs_ref, level_ref, pidx_ref,
-                          logz_ref, cavg_ref, *, num_levels: int,
-                          width: int, num_arcs: int):
-    """Fused general-DAG candidate-evaluation kernel, batch-blocked: the
-    in-kernel pieces of ``_loss_only_kernel`` (combined endpoint gather on
-    the centred cumsum grid, arc->level-major gather) plus the
-    frontier-recursion forward pass of ``_dag_fwd_kernel`` batched over B,
-    ending in the final-arc reduction.  Only the two (B,) outputs leave.
-
-    fcs: (B, 6, A) f32 packed [span, lm, corr, arc_mask, is_start,
-    is_final]; pidx: (B, L, W, P) predecessor positions.
-    """
-    cum = cum_ref[...]
-    g = jnp.take_along_axis(cum, idx_ref[...], axis=1)         # (B, 3A)
-    A = num_arcs
-    fcs = fcs_ref[...]
-    score_arc = (g[:, :A] - g[:, A:2 * A]
-                 + fcs[:, 0] * g[:, 2 * A:]) + fcs[:, 1]
-    la = level_ref[...]                                        # (B, L, W)
-    B = la.shape[0]
-    L, W = num_levels, width
-    LW = L * W
-    safe = jnp.maximum(la, 0).reshape(B, 1, LW)
-    stacked = jnp.stack([score_arc, fcs[:, 2], fcs[:, 3], fcs[:, 4],
-                         fcs[:, 5]], axis=1)                   # (B, 5, A)
-    gath = jnp.take_along_axis(stacked, safe, axis=2).reshape(B, 5, L, W)
-    empty = la < 0
-    score = jnp.where(empty, NEG, gath[:, 0])
-    corr = jnp.where(empty, 0.0, gath[:, 1])
-    ok = jnp.where(empty, 0.0, gath[:, 2]) > 0.5
-    start = (jnp.where(empty, 0.0, gath[:, 3]) > 0.5) & ok
-    fin = (jnp.where(empty, 0.0, gath[:, 4]) > 0.5) & ok
-    pidx = pidx_ref[...]                                       # (B, L, W, P)
-
-    def level_step(l, carry):
-        a_buf, c_buf = carry                                   # (B, LW+1)
-        pidx_l = jax.lax.dynamic_index_in_dim(pidx, l, 1, keepdims=False)
-        flat = pidx_l.reshape(B, -1)                           # (B, W*P)
-        pa = jnp.take_along_axis(a_buf, flat, axis=1).reshape(pidx_l.shape)
-        pc = jnp.take_along_axis(c_buf, flat, axis=1).reshape(pidx_l.shape)
-        in_log, w = _masked_lse_rows(pa)                       # (B, W)
-        c_in = jnp.sum(w * pc, axis=-1)
-        own_l = jax.lax.dynamic_index_in_dim(score, l, 1, keepdims=False)
-        corr_l = jax.lax.dynamic_index_in_dim(corr, l, 1, keepdims=False)
-        start_l = jax.lax.dynamic_index_in_dim(start, l, 1, keepdims=False)
-        ok_l = jax.lax.dynamic_index_in_dim(ok, l, 1, keepdims=False)
-        a_val = jnp.where(start_l, own_l, own_l + in_log)
-        c_val = corr_l + jnp.where(start_l, 0.0, c_in)
-        a_val = jnp.where(ok_l, a_val, NEG)
-        c_val = jnp.where(ok_l, c_val, 0.0)
-        a_buf = jax.lax.dynamic_update_slice(a_buf, a_val, (0, l * W))
-        c_buf = jax.lax.dynamic_update_slice(c_buf, c_val, (0, l * W))
-        return a_buf, c_buf
-
-    a_buf, c_buf = jax.lax.fori_loop(
-        0, L, level_step,
-        (jnp.full((B, LW + 1), NEG, jnp.float32),
-         jnp.zeros((B, LW + 1), jnp.float32)))
-    af = jnp.where(fin.reshape(B, LW), a_buf[:, :LW], NEG)
-    logz, w = _masked_lse_rows(af)                             # (B,)
-    logz_ref[...] = logz
-    cavg_ref[...] = jnp.sum(w * c_buf[:, :LW], axis=-1)
 
 
 def dag_loss_only(log_probs, start, end, label, lm, corr, arc_mask,
                   is_start, is_final, level_arcs, pidx, *,
                   kappa: float = 1.0, interpret: bool | None = None):
-    """Fused loss-only forward for GENERAL DAG lattices: (logZ (B,),
-    c_avg (B,)) straight from the frame log-probs and arc-layout lattice
-    fields, like :func:`sausage_loss_only`, but running the
-    frontier-recursion forward pass (predecessor-position gathers) instead
-    of the fully-connected segment recursion.
+    """Loss-only forward for GENERAL DAG lattices: (logZ (B,), c_avg (B,))
+    straight from the frame log-probs and arc-layout lattice fields, like
+    :func:`sausage_loss_only`, but running the frontier-recursion forward
+    pass (predecessor-position gathers) instead of the fully-connected
+    segment recursion.
 
     Extra inputs over the sausage variant: is_start/is_final (B, A) arc
     flags (finals may sit on any level) and pidx (B, L, W, P) predecessor
@@ -576,64 +466,13 @@ def dag_loss_only(log_probs, start, end, label, lm, corr, arc_mask,
     Not differentiable directly — ``lattice_engine.pallas_backend`` wraps
     it in a ``custom_jvp``.  Validated against ``ref.dag_loss_only_ref``.
     """
-    B, T, K = log_probs.shape
-    A = start.shape[1]
-    L, W = level_arcs.shape[1], level_arcs.shape[2]
-    lp = log_probs.astype(jnp.float32)
-    mu = jnp.mean(lp, axis=1)                                  # (B, K)
-    cum = jnp.cumsum(lp - mu[:, None, :], axis=1)
-    cum = jnp.concatenate([jnp.zeros_like(cum[:, :1]), cum], axis=1)
-    cumext = jnp.concatenate([cum.reshape(B, -1), mu], axis=1) * kappa
-    lab = label.astype(jnp.int32)
-    idx = jnp.concatenate(
-        [end.astype(jnp.int32) * K + lab, start.astype(jnp.int32) * K + lab,
-         (T + 1) * K + lab], axis=1)                           # (B, 3A)
-    span = (end - start).astype(jnp.float32)
-    fcs = jnp.stack([span, lm.astype(jnp.float32), corr.astype(jnp.float32),
-                     arc_mask.astype(jnp.float32),
-                     is_start.astype(jnp.float32),
-                     is_final.astype(jnp.float32)], axis=1)    # (B, 6, A)
-    kernel = functools.partial(_dag_loss_only_kernel, num_levels=L,
-                               width=W, num_arcs=A)
-    logz, cavg = instrument.pallas_call(
-        kernel,
-        out_shape=[
-            jax.ShapeDtypeStruct((B,), jnp.float32),
-            jax.ShapeDtypeStruct((B,), jnp.float32),
-        ],
-        interpret=resolve_interpret(interpret),
-    )(cumext, idx, fcs, level_arcs.astype(jnp.int32),
-      pidx.astype(jnp.int32))
-    return logz, cavg
-
-
-def sausage_backward(scores, corr, mask=None, *,
-                     interpret: bool | None = None):
-    """Backward (beta / c_beta) companion of :func:`sausage_forward`.
-
-    Returns (beta (B,S,A), c_beta (B,S,A)); beta excludes the arc's own
-    score (FBStats convention), so gamma = exp(alpha + beta - logZ).
-    """
-    B, S, A = scores.shape
-    if mask is None:
-        mask = _ones_mask(scores)
-    kernel = functools.partial(_bwd_kernel, num_segments=S)
-    beta, c_beta = instrument.pallas_call(
-        kernel,
-        grid=(B,),
-        in_specs=[
-            pl.BlockSpec((None, S, A), lambda b: (b, 0, 0)),
-            pl.BlockSpec((None, S, A), lambda b: (b, 0, 0)),
-            pl.BlockSpec((None, S, A), lambda b: (b, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((None, S, A), lambda b: (b, 0, 0)),
-            pl.BlockSpec((None, S, A), lambda b: (b, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, S, A), jnp.float32),
-            jax.ShapeDtypeStruct((B, S, A), jnp.float32),
-        ],
-        interpret=resolve_interpret(interpret),
-    )(scores, corr, mask.astype(jnp.float32))
-    return beta, c_beta
+    score_arc = _level_arc_scores(log_probs, start, end, label, lm, kappa)
+    ok = gather_sausage_ref(arc_mask.astype(_F32), level_arcs, 0.0)
+    tiles = (gather_sausage_ref(score_arc, level_arcs, NEG),
+             gather_sausage_ref(corr.astype(_F32), level_arcs, 0.0),
+             gather_sausage_ref(is_start.astype(_F32), level_arcs, 0.0) * ok,
+             ok,
+             gather_sausage_ref(is_final.astype(_F32), level_arcs, 0.0) * ok)
+    logz, cavg = _dag_call(_dag_loss_only_kernel, ("scalar", "scalar"),
+                           tiles, pidx, interpret, scratch_tiles=2)
+    return logz[:, 0, 0], cavg[:, 0, 0]

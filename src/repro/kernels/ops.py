@@ -1,17 +1,15 @@
 """Jitted public wrappers for the Pallas kernels.
 
 Every kernel auto-detects its mode through the ONE dispatch predicate in
-``kernels.dispatch``: compiled on TPU backends, interpret elsewhere (set
-``REPRO_PALLAS_COMPILED=1`` to force compiled).  Every wrapper has a
-pure-jnp fallback (ref.py) that is also what the distributed (GSPMD)
-model paths use — the kernels are the single-chip hot-spot
-implementations.
+``kernels.dispatch``: compiled on TPU backends, interpret elsewhere.
+Every wrapper has a pure-jnp fallback (ref.py) that is also what the
+distributed (GSPMD) model paths use — the kernels are the single-chip
+hot-spot implementations.
 """
 from __future__ import annotations
 
-from repro.kernels import ref
+from repro.kernels import dispatch, ref
 from repro.kernels.cg_fused import cg_fused_update as _cg_pallas
-from repro.kernels.dispatch import compiled_backend
 from repro.kernels.lattice_fb import dag_backward as _dag_bwd_pallas
 from repro.kernels.lattice_fb import dag_forward as _dag_fwd_pallas
 from repro.kernels.lattice_fb import dag_loss_only as _dag_loss_only_pallas
@@ -25,16 +23,14 @@ def swa_attention(q, k, v, window: int, *, use_pallas: bool = True):
     if not use_pallas:
         return ref.swa_attention_ref(q, k, v, window)
     # interpret=None auto-detects via kernels.dispatch (one source of
-    # truth for every kernel): compiled on TPU or with
-    # REPRO_PALLAS_COMPILED=1, interpreter elsewhere
+    # truth for every kernel): compiled on TPU, interpreter elsewhere
     return _swa_pallas(q, k, v, window, interpret=None)
 
 
 def sausage_forward(scores, corr, mask=None, *, use_pallas: bool = True):
     if not use_pallas:
         return ref.sausage_forward_ref(scores, corr, mask)
-    # interpret=None auto-detects: compiled on TPU or with
-    # REPRO_PALLAS_COMPILED=1, interpreter elsewhere (lattice_fb handles it)
+    # interpret=None auto-detects: compiled on TPU, interpreter elsewhere
     return _fb_pallas(scores, corr, mask, interpret=None)
 
 
@@ -47,10 +43,10 @@ def sausage_backward(scores, corr, mask=None, *, use_pallas: bool = True):
 def sausage_loss_only(log_probs, start, end, label, lm, corr, arc_mask,
                       level_arcs, *, kappa: float = 1.0,
                       use_pallas: bool = True):
-    """Fused candidate-evaluation forward: (logZ, c_avg) straight from the
-    (B, T, K) frame log-probs + arc-layout lattice fields (score
-    construction and the arc->sausage gather both happen in-graph /
-    in-kernel; no per-arc statistics materialised)."""
+    """Candidate-evaluation forward: (logZ, c_avg) straight from the
+    (B, T, K) frame log-probs + arc-layout lattice fields (scores and the
+    arc->sausage gather built in XLA, the forward-only recursion in the
+    kernel; no per-arc statistics materialised)."""
     if not use_pallas:
         return ref.sausage_loss_only_ref(log_probs, start, end, label, lm,
                                          corr, arc_mask, level_arcs,
@@ -81,10 +77,10 @@ def dag_backward(own, corr, final, ok, sidx, *, use_pallas: bool = True):
 def dag_loss_only(log_probs, start, end, label, lm, corr, arc_mask,
                   is_start, is_final, level_arcs, pidx, *,
                   kappa: float = 1.0, use_pallas: bool = True):
-    """Fused general-DAG candidate-evaluation forward: (logZ, c_avg)
-    straight from the (B, T, K) frame log-probs + arc-layout lattice
-    fields + the levelized frontier tensors (score construction, the
-    arc->level-major gather and the frontier recursion all in-kernel)."""
+    """General-DAG candidate-evaluation forward: (logZ, c_avg) straight
+    from the (B, T, K) frame log-probs + arc-layout lattice fields + the
+    levelized frontier tensors (scores and the arc->level-major gather in
+    XLA, the forward-only frontier recursion in the kernel)."""
     if not use_pallas:
         return ref.dag_loss_only_ref(log_probs, start, end, label, lm,
                                      corr, arc_mask, is_start, is_final,
@@ -100,12 +96,12 @@ def cg_fused_update(alpha, x, v, r, bv, *, use_pallas: bool | None = None):
 
     ``use_pallas=None`` (the default, what ``core.cg.cg_solve(fused=True)``
     uses) auto-dispatches on ``kernels.dispatch.compiled_backend()``: the
-    Pallas kernel where it compiles (TPU, or ``REPRO_PALLAS_COMPILED=1``),
-    the fused pure-jnp reference elsewhere — interpret-mode Pallas would
-    only add per-block overhead on CPU while XLA already fuses the ref's
-    AXPY+dot chain into one loop."""
+    Pallas kernel where it compiles (TPU), the fused pure-jnp reference
+    elsewhere — interpret-mode Pallas would only add per-block overhead
+    on CPU while XLA already fuses the ref's AXPY+dot chain into one
+    loop."""
     if use_pallas is None:
-        use_pallas = compiled_backend()
+        use_pallas = dispatch.compiled_backend()
     if not use_pallas:
         return ref.cg_fused_update_ref(alpha, x, v, r, bv)
     return _cg_pallas(alpha, x, v, r, bv, interpret=None)

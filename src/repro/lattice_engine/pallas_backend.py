@@ -34,18 +34,16 @@ The auxiliary arc statistics (alpha, beta, gamma, ...) are returned as
 differentiate ``logZ``/``c_avg``, and under jit the unused direct kernel
 calls are dead-code-eliminated.
 
-``accumulators="loss_only"`` routes through the FUSED candidate-evaluation
-kernel instead (``kernels.lattice_fb.sausage_loss_only``): one batched
-streaming pass turns the (B,T,K) log-probs into the centred cumsum grid,
-and everything downstream — the span-endpoint gather that builds the
-per-arc scores, the arc->(S,W) sausage gather, and the forward recursion
-— happens inside one batch-blocked kernel.  No (B,A)/(B,S,W) score
-tensors, no per-arc statistics, and no backward kernel appear in the
-graph; only ``(logZ, c_avg)`` come back.  Its ``custom_jvp`` uses the
-same occupancy identities — the tangent rule *does* materialise scores
-and run the kernel pair (gradient and R-operator passes need gamma
-anyway); the fused path is the pure *value* evaluation that CG candidate
-selection executes per iteration.
+``accumulators="loss_only"`` routes through the candidate-evaluation
+kernels instead (``kernels.lattice_fb.sausage_loss_only`` /
+``dag_loss_only``): XLA turns the (B,T,K) log-probs into per-arc scores
+(centred cumsum endpoint gather) and gathers them into the (S,W) level
+layout, and one forward-only kernel runs the recursion.  No per-arc
+statistics and no backward kernel appear in the graph; only
+``(logZ, c_avg)`` come back.  Its ``custom_jvp`` uses the same occupancy
+identities — the tangent rule runs the kernel pair (gradient and
+R-operator passes need gamma anyway); the loss-only path is the pure
+*value* evaluation that CG candidate selection executes per iteration.
 """
 from __future__ import annotations
 
@@ -127,11 +125,10 @@ def fused_sausage_loss_only(kappa, log_probs, start, end, label, lm, corr,
     level_arcs gather map.  ``kappa`` is a regular primal (it is folded
     into the cumsum grid, so traced values work) with its own tangent.
 
-    The primal is ONE forward-only Pallas kernel (scores and the
-    arc->sausage gather built in-kernel, nothing but the two (B,) outputs
-    materialised).  The tangent rule falls back to materialised scores +
-    the kernel pair for gamma/c_arc — candidate evaluation never triggers
-    it; gradient passes do, and they need the full statistics regardless.
+    The primal is ONE forward-only Pallas kernel (nothing but the two
+    (B,) outputs leaves it).  The tangent rule runs the kernel pair for
+    gamma/c_arc — candidate evaluation never triggers it; gradient passes
+    do, and they need the full statistics regardless.
     """
     return sausage_loss_only(log_probs, start, end, label, lm, corr,
                              arc_mask, level_arcs, kappa=kappa)
@@ -336,9 +333,9 @@ def _forward_backward_dag_pallas(lat: Lattice, log_probs: jnp.ndarray,
 
 def _loss_only_pallas(lat: Lattice, log_probs: jnp.ndarray, kappa: float,
                       constrain) -> LossStats:
-    """The fused candidate-evaluation path: raw arc-layout lattice fields
-    in, (logZ, c_avg) out — no score gather, no per-arc statistics, no
-    backward kernel anywhere in the graph."""
+    """The candidate-evaluation path: raw arc-layout lattice fields in,
+    (logZ, c_avg) out — no per-arc statistics, no backward kernel
+    anywhere in the graph."""
     c = constrain
     logZ, c_avg = fused_sausage_loss_only(
         kappa, c(log_probs.astype(jnp.float32)),

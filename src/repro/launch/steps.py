@@ -45,6 +45,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
 from repro.core.optim import Optimizer, get_optimizer
+from repro.kernels import dispatch
 from repro.losses.chunked_lm import ChunkedCELoss
 from repro.models.registry import get_model
 
@@ -88,6 +89,16 @@ def cg_sub_batch(batch: dict, frac: int, min_size: int):
     return jax.tree.map(slc, batch)
 
 
+# XLA's TPU compiler places the output-layer VJP fusion of the CG-stage
+# curvature product — the (B, T, K) cotangent, its bias reduction and its
+# bf16 copy for the weight matmul — in VMEM and then overruns the default
+# 16 MiB scoped-VMEM limit (lstm-asr, CG batch 8 x 128 frames x 6000
+# outputs: 17.4 MiB; 40.6 MiB at "highest" matmul precision), refusing
+# the whole step.  A v5e core has 128 MiB of VMEM; 64 MiB of scoped
+# limit compiles the full-width step at either precision.
+TPU_COMPILER_OPTIONS = {"xla_tpu_scoped_vmem_limit_kib": "65536"}
+
+
 def jit_train_step(step: Callable, **jit_kwargs) -> Callable:
     """jit a train step donating ``(params, opt_state)`` — args 0 and 1 of
     every builder here.
@@ -101,8 +112,12 @@ def jit_train_step(step: Callable, **jit_kwargs) -> Callable:
     use the step's OUTPUTS, which ``checkpoint.io`` copies to host
     eagerly).  The graph auditor (``repro.analysis.graph_audit``) checks
     the resulting ``input_output_alias`` on every train graph.
+
+    On a TPU the step also gets ``TPU_COMPILER_OPTIONS``.
     """
     jit_kwargs.setdefault("donate_argnums", (0, 1))
+    if dispatch.compiled_backend():
+        jit_kwargs.setdefault("compiler_options", TPU_COMPILER_OPTIONS)
     return jax.jit(step, **jit_kwargs)
 
 

@@ -36,6 +36,7 @@ from repro.core.optim import config_for, list_optimizers
 from repro.data.pipeline import shard_batch
 from repro.data.synthetic import EpochPlan, asr_batch, lm_batch
 from repro.launch import steps as S
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_debug_mesh, make_production_mesh
 from repro.launch.sharding import (data_extent, input_shardings,
                                    param_shardings,
@@ -287,6 +288,7 @@ def main(argv=None):
     ap.add_argument("--cg-batch", type=int, default=8)
     ap.add_argument("--lattice-backend", default="auto")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.arch in ASR_ARCHS:
         _, log = train_sequence(

@@ -32,6 +32,7 @@ from collections import deque
 
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving import packing
 from repro.serving.metrics import latency_summary
 from repro.serving.streaming import (StreamSession, session_bucket,
@@ -259,6 +260,7 @@ def main(argv=None):  # reprolint: host
     ap.add_argument("--backend", default="auto")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     n = min(args.requests, 12) if args.smoke else args.requests
     reqs = synthetic_workload(args.seed, n, rate_hz=args.rate_hz)

@@ -103,8 +103,7 @@ def test_audit_text_combines_rules():
 # --------------------------------------------------------------------------
 
 def test_f64_leak_detected_in_real_lowering():
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with jax.enable_x64(True):
         text = jax.jit(lambda x: x.astype(jnp.float64) * 2).lower(
             jax.ShapeDtypeStruct((4,), jnp.float32)).compile().as_text()
     assert rules_graph.find_f64(text)
