@@ -31,18 +31,14 @@ Emits the standard CSV rows plus one JSON row per optimiser:
     {"bench": "optim_update", "optimizer": "nghf_fast", ...,
      "ms_per_update": 61.2, "cg_iters_used": 3, "cg_best_loss": -0.41}
 
-and a per-phase CG-stage cost breakdown (paper Table 1's decomposition):
-
-    {"bench": "cg_phase", "phase": "curvature_product",
-     "curvature_sample": 1.0, "ms": 5.1}
-
-phases: ``curvature_product`` (one GN product, at sample 1.0 and 0.5),
-``candidate_eval`` (one loss-only evaluation on the full CG batch) and
-``vector_work`` (one x/r/rr iteration update, fused vs unfused).
+The update's per-stage device time (gradient stage, curvature products,
+candidate evaluations, CG vector work, lattice statistics) is read from a
+chip trace of the one jitted update by the benchmark's per-stage metrics
+(``bench/metrics/``), not timed here.
 
 ``--json-out BENCH_lattice.json`` MERGES these rows into the existing
 lattice-engine trajectory file (same CI artifact), replacing any previous
-``optim_update`` / ``cg_phase`` rows.
+``optim_update`` rows.
 """
 from __future__ import annotations
 
@@ -83,61 +79,6 @@ CONFIGS = [
                            "warm_start": True, "cg_tol": 0.2,
                            "curvature_sample": 0.5, "cg_fused": True}),
 ]
-
-
-def phase_breakdown(cfg, params, counts, cb):
-    """Per-phase CG-stage costs (paper Table 1): ONE curvature product,
-    ONE candidate evaluation, ONE iteration of vector work — each jitted
-    standalone so the row isolates that phase's wall time."""
-    from repro.core import tree_math as tm
-    from repro.core.curvature import make_curvature_ops
-    from repro.kernels import ops as kernel_ops
-    from repro.losses.sequence import get_loss
-
-    loss_spec = get_loss("mpe", kappa=0.5)
-    fwd = lambda p, b: (acoustic.forward(cfg, p, b["feats"]), 0.0)  # noqa
-    v = jax.tree.map(lambda x: jnp.ones_like(x) * 1e-3, params)
-    rows = []
-
-    for frac in (1.0, 0.5):
-        ops_f = make_curvature_ops(fwd, loss_spec, params, cb,
-                                   eval_accumulators="loss_only",
-                                   curvature_sample=frac)
-        us = time_call(jax.jit(ops_f.gnvp), v, warmup=1, iters=3)
-        emit(f"cg_phase.curvature_product.s{frac}", us, f"ms={us / 1e3:.3f}")
-        rows.append({"bench": "cg_phase", "phase": "curvature_product",
-                     "curvature_sample": frac, "cg_B": BATCH_CG,
-                     "ms": round(us / 1e3, 4)})
-        if frac == 1.0:
-            us = time_call(jax.jit(ops_f.eval_loss), v, warmup=1, iters=3)
-            emit("cg_phase.candidate_eval.loss_only", us,
-                 f"ms={us / 1e3:.3f}")
-            rows.append({"bench": "cg_phase", "phase": "candidate_eval",
-                         "accumulators": "loss_only", "cg_B": BATCH_CG,
-                         "ms": round(us / 1e3, 4)})
-
-    # vector work: one x/r/rr update on a θ-sized flat buffer
-    from jax.flatten_util import ravel_pytree
-    flat, _ = ravel_pytree(params)
-    n = flat.size
-    key = jax.random.PRNGKey(1)
-    x, vv, r, bv = (jax.random.normal(jax.random.fold_in(key, i), (n,))
-                    for i in range(4))
-
-    def unfused(alpha, x, vv, r, bv):
-        xn = tm.axpy(alpha, vv, x)
-        rn = tm.axpy(-alpha, bv, r)
-        return xn, rn, tm.vdot(rn, rn)
-
-    for name, fn in (("fused", jax.jit(kernel_ops.cg_fused_update)),
-                     ("unfused", jax.jit(unfused))):
-        us = time_call(fn, jnp.float32(0.3), x, vv, r, bv,
-                       warmup=2, iters=5)
-        emit(f"cg_phase.vector_work.{name}", us, f"ms={us / 1e3:.3f}")
-        rows.append({"bench": "cg_phase", "phase": "vector_work",
-                     "variant": name, "n": int(n),
-                     "ms": round(us / 1e3, 4)})
-    return rows
 
 
 def donation_row(cfg, params, counts, gb, cb):
@@ -298,7 +239,6 @@ def run(budget: str = "small", json_out: str | None = None):
 
     json_rows.append(donation_row(cfg, params, counts, gb, cb))
     json_rows.append(sharded_lm_row())
-    json_rows += phase_breakdown(cfg, params, counts, cb)
 
     if json_out:
         # merge into the shared trajectory file (one CI artifact for both
@@ -309,8 +249,7 @@ def run(budget: str = "small", json_out: str | None = None):
             with open(json_out) as f:
                 doc = json.load(f)
         doc["rows"] = [r for r in doc.get("rows", [])
-                       if r.get("bench") not in ("optim_update", "cg_phase")
-                       ] + json_rows
+                       if r.get("bench") != "optim_update"] + json_rows
         with open(json_out, "w") as f:
             json.dump(doc, f, indent=1)
         print(f"# merged {len(json_rows)} optim rows into {json_out}")

@@ -3,7 +3,6 @@ the roofline report (deliverables d and g).
 
 Prints ``name,us_per_call,derived`` CSV rows (one per measured artifact).
 
-  table1_timing       — paper Table 1 (CG stage time split)
   table2_optimisers   — paper Tables 2/3 + Fig. 2 (optimiser comparison)
   table45_activations — paper Tables 4/5 (ReLU vs sigmoid, RNN/TDNN)
   cg_stability        — Sec. 4.2 (‖θ‖/‖v‖ rescaling) ablation
@@ -28,9 +27,8 @@ def main() -> None:
     t0 = time.time()
     print("name,us_per_call,derived")
     from benchmarks import (cg_stability, kernel_bench, lattice_engine_bench,
-                            optim_bench, precond_ablation, table1_timing,
-                            table2_optimisers, table45_activations)
-    table1_timing.run()
+                            optim_bench, precond_ablation, table2_optimisers,
+                            table45_activations)
     table2_optimisers.run()
     table45_activations.run()
     cg_stability.run()

@@ -137,7 +137,8 @@ def make_curvature_ops(forward_fn, loss_spec, params, batch, *,
         return forward_fn(p, curv_batch)[0]
 
     if mode == "linearize":
-        logits, jvp_fn = jax.linearize(f, params)
+        with jax.named_scope("curvature_product"):
+            logits, jvp_fn = jax.linearize(f, params)
         vjp_fn = jax.linear_transpose(jvp_fn, params)
     else:
         logits = None
@@ -154,27 +155,28 @@ def make_curvature_ops(forward_fn, loss_spec, params, batch, *,
         theta_norm = tm.norm(params)
 
     def _product(factor_vp, v):
-        if stabilize:
-            v_norm = jnp.maximum(tm.norm(v), 1e-30)
-            s = theta_norm / v_norm
-            v_in = tm.scale(v, s)
-        else:
-            s = 1.0
-            v_in = v
-        # JVP requires tangent dtype == primal dtype (bf16 CG state vs
-        # f32 master params)
-        v_in = tm.cast_like(v_in, params)
-        if mode == "linearize":
-            out_primal = logits
-            jv = jvp_fn(v_in)
-            hu = factor_vp(out_primal, curv_batch, jv)
-            (out,) = vjp_fn(hu)
-        else:
-            out_primal, jv = jax.jvp(f, (params,), (v_in,))
-            hu = factor_vp(out_primal, curv_batch, jv)
-            _, pullback = jax.vjp(f, params)
-            (out,) = pullback(hu)
-        return tm.scale(out, 1.0 / s) if stabilize else out
+        with jax.named_scope("curvature_product"):
+            if stabilize:
+                v_norm = jnp.maximum(tm.norm(v), 1e-30)
+                s = theta_norm / v_norm
+                v_in = tm.scale(v, s)
+            else:
+                s = 1.0
+                v_in = v
+            # JVP requires tangent dtype == primal dtype (bf16 CG state vs
+            # f32 master params)
+            v_in = tm.cast_like(v_in, params)
+            if mode == "linearize":
+                out_primal = logits
+                jv = jvp_fn(v_in)
+                hu = factor_vp(out_primal, curv_batch, jv)
+                (out,) = vjp_fn(hu)
+            else:
+                out_primal, jv = jax.jvp(f, (params,), (v_in,))
+                hu = factor_vp(out_primal, curv_batch, jv)
+                _, pullback = jax.vjp(f, params)
+                (out,) = pullback(hu)
+            return tm.scale(out, 1.0 / s) if stabilize else out
 
     def gnvp(v):
         return _product(loss_spec.gn_vp, v)
@@ -198,13 +200,15 @@ def make_curvature_ops(forward_fn, loss_spec, params, batch, *,
             eval_kw = {"accumulators": eval_accumulators}
 
     def eval_loss(delta):
-        lg, aux = forward_fn(tm.add(params, tm.cast_like(delta, params)),
-                             batch)
-        # include the scaled auxiliary loss: grad_and_loss minimises
-        # ``loss + aux``, so Alg. 1 candidate selection / reject_worse
-        # must rank candidates by the SAME objective (dropping aux made
-        # selection compare a different function than the one optimised)
-        return loss_spec.value(lg, batch, **eval_kw)[0] + aux
+        with jax.named_scope("candidate_eval"):
+            lg, aux = forward_fn(
+                tm.add(params, tm.cast_like(delta, params)), batch)
+            # include the scaled auxiliary loss: grad_and_loss minimises
+            # ``loss + aux``, so Alg. 1 candidate selection / reject_worse
+            # must rank candidates by the SAME objective (dropping aux
+            # made selection compare a different function than the one
+            # optimised)
+            return loss_spec.value(lg, batch, **eval_kw)[0] + aux
 
     return CurvatureOps(gnvp=gnvp, fvp=fvp, eval_loss=eval_loss, logits=logits)
 
@@ -222,37 +226,39 @@ def grad_and_loss(forward_fn, loss_spec, params, batch, *,
     accumulated-gradient scan carry on its storage sharding.
     """
 
-    def obj(p, b):
-        logits, aux = forward_fn(p, b)
-        loss, metrics = loss_spec.value(logits, b)
-        # ``aux`` is the already-scaled auxiliary loss (e.g. MoE router
-        # load-balance, scaled by cfg.router_aux_coef in the step builder).
-        return loss + aux, metrics
+    with jax.named_scope("grad_stage"):
+        def obj(p, b):
+            logits, aux = forward_fn(p, b)
+            loss, metrics = loss_spec.value(logits, b)
+            # ``aux`` is the already-scaled auxiliary loss (e.g. MoE router
+            # load-balance, scaled by cfg.router_aux_coef where the step is
+            # built).
+            return loss + aux, metrics
 
-    if microbatches <= 1:
-        (loss, metrics), grads = jax.value_and_grad(
-            obj, has_aux=True)(params, batch)
+        if microbatches <= 1:
+            (loss, metrics), grads = jax.value_and_grad(
+                obj, has_aux=True)(params, batch)
+            return loss, metrics, grads
+
+        B = jax.tree.leaves(batch)[0].shape[0]
+        k = microbatches
+        assert B % k == 0, (B, k)
+        split = jax.tree.map(
+            lambda x: x.reshape((k, B // k) + x.shape[1:])
+            if hasattr(x, "ndim") and x.ndim >= 1 and x.shape[0] == B else x,
+            batch)
+        ident = constrain if constrain is not None else (lambda t: t)
+
+        def body(carry, mb):
+            acc, loss_acc = carry
+            (loss, metrics), grads = jax.value_and_grad(
+                obj, has_aux=True)(params, mb)
+            acc = ident(jax.tree.map(lambda a, g: a + g / k, acc, grads))
+            return (acc, loss_acc + loss / k), metrics
+
+        zeros = ident(jax.tree.map(jnp.zeros_like, params))
+        (grads, loss), metrics = jax.lax.scan(body, (zeros, jnp.float32(0.0)),
+                                              split)
+        metrics = jax.tree.map(lambda m: m.mean(0) if hasattr(m, "ndim") and
+                               m.ndim >= 1 else m, metrics)
         return loss, metrics, grads
-
-    B = jax.tree.leaves(batch)[0].shape[0]
-    k = microbatches
-    assert B % k == 0, (B, k)
-    split = jax.tree.map(
-        lambda x: x.reshape((k, B // k) + x.shape[1:])
-        if hasattr(x, "ndim") and x.ndim >= 1 and x.shape[0] == B else x,
-        batch)
-    ident = constrain if constrain is not None else (lambda t: t)
-
-    def body(carry, mb):
-        acc, loss_acc = carry
-        (loss, metrics), grads = jax.value_and_grad(
-            obj, has_aux=True)(params, mb)
-        acc = ident(jax.tree.map(lambda a, g: a + g / k, acc, grads))
-        return (acc, loss_acc + loss / k), metrics
-
-    zeros = ident(jax.tree.map(jnp.zeros_like, params))
-    (grads, loss), metrics = jax.lax.scan(body, (zeros, jnp.float32(0.0)),
-                                          split)
-    metrics = jax.tree.map(lambda m: m.mean(0) if hasattr(m, "ndim") and
-                           m.ndim >= 1 else m, metrics)
-    return loss, metrics, grads

@@ -311,8 +311,10 @@ class SecondOrderOptimizer(Optimizer):
         delta = tm.scale(res.x, cfg.step_scale)
         accepted = jnp.asarray(True)
         base = None
+        cg_evals = res.evals
         if cfg.eval_candidates and (cfg.reject_worse or cfg.adapt_lam):
             base = ops.eval_loss(tm.zeros_like(res.x))
+            cg_evals = cg_evals + 1          # the zero update's evaluation
         if cfg.eval_candidates and cfg.reject_worse:
             # Alg. 1 returns the best candidate by CG-batch loss;
             # additionally reject it if it does not beat the zero update
@@ -358,7 +360,7 @@ class SecondOrderOptimizer(Optimizer):
             cg_best_iter=res.best_iter, cg_best_loss=res.best_loss,
             cg_quad=res.quad, cg_resid=res.resid, cg_curv=res.curv,
             cg_losses=res.losses, cg_accepted=accepted,
-            cg_iters_used=res.iters_used,
+            cg_iters_used=res.iters_used, cg_evals=cg_evals,
             opt_step=new_state["step"], **diag)
         return new_params, new_state, metrics
 

@@ -122,5 +122,6 @@ def lattice_stats(lat: Lattice, log_probs, kappa: float,
       former; tested equal to the scan backend's autodiff).
     """
     check_accumulators(accumulators)
-    return _DISPATCH[resolve_backend(backend, lat)](
-        lat, log_probs, kappa, mesh=mesh, accumulators=accumulators)
+    with jax.named_scope("lattice_stats"):
+        return _DISPATCH[resolve_backend(backend, lat)](
+            lat, log_probs, kappa, mesh=mesh, accumulators=accumulators)

@@ -21,6 +21,7 @@ Examples:
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 import os
 import time
@@ -89,7 +90,7 @@ def train_sequence(*, arch=None, acfg=None, optimizer="nghf", loss="mpe",
                    dataset_batches=None, ckpt_every=10, warm_start=False,
                    adapt_lam=False, preconditioner=None,
                    curvature_sample=None, curvature_sample_schedule=None,
-                   cg_tol=None, cg_fused=False):
+                   cg_tol=None, cg_fused=False, profile_dir=None):
     """Lattice MPE/MMI (or frame-CE) training of an acoustic model through
     the distributed launch layer.  Returns ``(params, log)``.
 
@@ -107,6 +108,16 @@ def train_sequence(*, arch=None, acfg=None, optimizer="nghf", loss="mpe",
     epochs, the paper's regime); when None every update draws a fresh
     batch.  ``seed`` offsets the whole stream so separate stages (e.g. CE
     pretraining vs MPE) can use disjoint data.
+
+    ``profile_dir``: when set, the updates after the first (which
+    compiles) run under ``jax.profiler.trace(profile_dir)``.  The loop's
+    host spans (``train.make_batch``, ``train.update``,
+    ``train.read_metrics``, ``train.checkpoint``, ``train.rebuild``) land
+    in the same trace as the device's operations, so each device-idle gap
+    falls under the host span that caused it; the update's own stages are
+    named scopes inside the program (``grad_stage``,
+    ``curvature_product``, ``candidate_eval``, ``cg_solve``,
+    ``lattice_stats``).
     """
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.models import acoustic
@@ -182,37 +193,51 @@ def train_sequence(*, arch=None, acfg=None, optimizer="nghf", loss="mpe",
         return plan.grad_seed(0, u % dataset_batches if dataset_batches
                               else u)
 
+    span = jax.profiler.TraceAnnotation
     log = []
     cur_frac = None
-    for u in range(start, steps):
-        t0 = time.time()
-        want = sched_frac(u) if opt.uses_cg_batch else None
-        if want is not None and want != cur_frac:
-            # curvature-sample schedule boundary: the sample is a STATIC
-            # slice (jit-friendly), so a new fraction means one rebuild +
-            # recompile per phase — a handful over a whole run.  The
-            # optimiser state is untouched (curvature_sample does not
-            # enter the state template).
-            step, opt = build(want)
-            cur_frac = want
+    with contextlib.ExitStack() as profiling:
+        for u in range(start, steps):
+            if profile_dir and u == start + 1:
+                profiling.enter_context(jax.profiler.trace(profile_dir))
+            t0 = time.time()
+            want = sched_frac(u) if opt.uses_cg_batch else None
+            if want is not None and want != cur_frac:
+                # curvature-sample schedule boundary: the sample is a
+                # STATIC slice (jit-friendly), so a new fraction means one
+                # rebuild + recompile per phase — a handful over a whole
+                # run.  The optimiser state is untouched
+                # (curvature_sample does not enter the state template).
+                with span("train.rebuild"):
+                    step, opt = build(want)
+                cur_frac = want
+                if verbose:
+                    print(f"  [curvature-sample] step {u}: fraction -> "
+                          f"{want}")
+            with span("train.make_batch"):
+                gb = make_batch(grad_seed(u), batch)
+                cb = make_batch(plan.cg_seed(0, u), cg_batch) \
+                    if opt.uses_cg_batch else None
+            with span("train.update"):
+                params, opt_state, metrics = step(params, opt_state, gb, cb)
+            with span("train.read_metrics"):
+                metrics = {k: float(v) for k, v in metrics.items()
+                           if getattr(v, "ndim", 0) == 0}
+            dt = time.time() - t0
+            log.append(dict(step=u, time_s=dt, **metrics))
             if verbose:
-                print(f"  [curvature-sample] step {u}: fraction -> {want}")
-        gb = make_batch(grad_seed(u), batch)
-        cb = make_batch(plan.cg_seed(0, u), cg_batch) \
-            if opt.uses_cg_batch else None
-        params, opt_state, metrics = step(params, opt_state, gb, cb)
-        metrics = {k: float(v) for k, v in metrics.items()
-                   if getattr(v, "ndim", 0) == 0}
-        dt = time.time() - t0
-        log.append(dict(step=u, time_s=dt, **metrics))
-        if verbose:
-            key_metric = metrics.get("mpe_acc", metrics.get(
-                "mmi", metrics.get("ce", metrics.get("loss", float("nan")))))
-            print(f"  seq step {u:4d} {loss}={key_metric:.4f} ({dt:.1f}s)")
-        if ckpt_dir and (u + 1) % ckpt_every == 0:
-            save_train_state(ckpt_dir, params, opt_state, step=u + 1)
-    if ckpt_dir:
-        save_train_state(ckpt_dir, params, opt_state, step=steps)
+                key_metric = metrics.get("mpe_acc", metrics.get(
+                    "mmi", metrics.get("ce", metrics.get("loss",
+                                                         float("nan")))))
+                print(f"  seq step {u:4d} {loss}={key_metric:.4f} "
+                      f"({dt:.1f}s)")
+            if ckpt_dir and (u + 1) % ckpt_every == 0:
+                with span("train.checkpoint"):
+                    save_train_state(ckpt_dir, params, opt_state,
+                                     step=u + 1)
+        if ckpt_dir:
+            with span("train.checkpoint"):
+                save_train_state(ckpt_dir, params, opt_state, step=steps)
     return params, log
 
 
@@ -281,6 +306,11 @@ def main(argv=None):
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--log-json", default=None)
+    ap.add_argument("--profile-dir", default=None,
+                    help="trace the updates after the first into this "
+                    "directory (jax.profiler; open with TensorBoard or "
+                    "Perfetto): the loop's train.* host spans and the "
+                    "update's named stages on one clock (ASR archs only)")
     # lattice sequence training (``*-asr`` archs) only:
     ap.add_argument("--loss", default="mpe", choices=["mpe", "mmi", "ce"])
     ap.add_argument("--kappa", type=float, default=0.5)
@@ -302,7 +332,8 @@ def main(argv=None):
             preconditioner=args.preconditioner,
             curvature_sample=args.curvature_sample,
             curvature_sample_schedule=args.curvature_sample_schedule,
-            cg_tol=args.cg_tol, cg_fused=args.cg_fused)
+            cg_tol=args.cg_tol, cg_fused=args.cg_fused,
+            profile_dir=args.profile_dir)
         if args.log_json:
             with open(args.log_json, "w") as f:
                 json.dump(log, f, indent=1)

@@ -64,6 +64,40 @@ def test_negative_curvature_freezes(rng):
     np.testing.assert_allclose(np.asarray(res.x["x"]), 0.0)
 
 
+@pytest.mark.parametrize("tol", [0.0, 1e-9])
+def test_evals_stop_where_the_curvature_guard_fires(tol):
+    """diag(1, 3, -1) against b = (1, 1, 0.2): v^T B v turns negative at
+    iteration 2, so the two iterates before it are the only candidates
+    evaluated.  The fixed-budget scan still runs all 5 iterations; the
+    adaptive loop stops on the guard after 3."""
+    A = jnp.asarray(np.diag([1.0, 3.0, -1.0]), jnp.float32)
+    b = jnp.asarray([1.0, 1.0, 0.2], jnp.float32)
+    res = cg_solve(lambda v: {"x": A @ v["x"]}, {"x": b}, iters=5,
+                   eval_fn=lambda x: 0.5 * x["x"] @ (A @ x["x"])
+                   - x["x"] @ b, eval_every=1, tol=tol)
+    curv = np.asarray(res.curv)
+    assert np.all(curv[:2] > 0) and curv[2] <= 0
+    assert int(res.evals) == 2
+    assert int(np.isfinite(np.asarray(res.losses)).sum()) == 2
+    assert int(res.iters_used) == (5 if tol == 0.0 else 3)
+
+
+@pytest.mark.parametrize("eval_every,evals", [(1, 6), (2, 4), (None, 0)])
+def test_evals_count_a_normal_solve(eval_every, evals):
+    """Without the guard every iterate on the stride is evaluated, and the
+    final one always: 6 iterations evaluate 6, at stride 2 iterations 0,
+    2, 4 and the final 5; without eval_fn none."""
+    n = 8
+    rng = np.random.default_rng(13)  # the shared rng fixture feeds later tests
+    A = jnp.asarray(_spd(rng, n), jnp.float32)
+    b = {"x": jnp.asarray(rng.standard_normal(n), jnp.float32)}
+    res = cg_solve(lambda v: {"x": A @ v["x"]}, b, iters=6,
+                   eval_fn=(lambda x: jnp.sum(x["x"] ** 2))
+                   if eval_every else None, eval_every=eval_every or 1)
+    assert int(res.iters_used) == 6
+    assert int(res.evals) == evals
+
+
 def test_candidate_selection_picks_best():
     # eval_fn rewards a specific iteration count
     A = np.diag(np.linspace(1, 3, 6)).astype(np.float32)
