@@ -93,19 +93,28 @@ def sausage_arc_scores_ref(log_probs, start, end, label, kappa: float):
     ``lattice_engine.common.arc_scores``), for any common index shape
     (B, ...) — arc layout (B, A) or sausage layout (B, S, W).
 
+    The gather reads each endpoint (b, t, label) of the (B, T, K) cumsum
+    in place, the empty prefix (t = 0) as 0: on the TPU's tiled layout a
+    flatten of the cumsum, or a zero row prepended to it, is a relayout
+    copy of the whole grid (and of its tangent and cotangent).
+
     Linear in ``log_probs`` — the fused kernel's ``custom_jvp`` applies
     this very function to the tangents.
     """
-    B, T, K = log_probs.shape
+    B = log_probs.shape[0]
     shp = start.shape
     lp = log_probs.astype(jnp.float32)
     mu = jnp.mean(lp, axis=1, keepdims=True)                  # (B, 1, K)
-    cum = jnp.cumsum(lp - mu, axis=1)
-    cum = jnp.concatenate([jnp.zeros_like(cum[:, :1]), cum], axis=1)
-    flat = cum.reshape(B, (T + 1) * K)                        # (B, (T+1)K)
+    cum = jnp.cumsum(lp - mu, axis=1)                         # (B, T, K)
+    bi = jnp.arange(B)[:, None]
     lab = label.reshape(B, -1).astype(jnp.int32)
-    hi = jnp.take_along_axis(flat, end.reshape(B, -1) * K + lab, axis=1)
-    lo = jnp.take_along_axis(flat, start.reshape(B, -1) * K + lab, axis=1)
+
+    def prefix(t):                     # sum over frames [0, t) at (b, lab)
+        row = t.reshape(B, -1).astype(jnp.int32) - 1
+        return jnp.where(row >= 0, cum[bi, jnp.maximum(row, 0), lab], 0.0)
+
+    hi = prefix(end)
+    lo = prefix(start)
     span = (end - start).reshape(B, -1).astype(jnp.float32)
     mu_lab = jnp.take_along_axis(mu[:, 0, :], lab, axis=1)
     return (kappa * (hi - lo + span * mu_lab)).reshape(shp)

@@ -59,9 +59,10 @@ def arc_scores(lat: Lattice, log_probs: jnp.ndarray, kappa: float):
 
     log_probs: (B, T, K) frame log-probabilities (log_softmax of logits).
     Returns (B, A) f32.  Cumulative-sums the (T, K) grid once, then
-    gathers only the 2A span endpoints ((t, label) pairs flattened to one
-    axis) — O(T*K) streaming work + O(A) gathered elements, instead of
-    materialising a (T, A) per-arc gather.
+    gathers only the 2A span endpoints, reading each (b, t, label) of the
+    cumsum in place — O(T*K) streaming work + O(A) gathered elements,
+    instead of materialising a (T, A) per-arc gather.  On the TPU's tiled
+    layout a flatten of the grid to one axis was a relayout copy.
 
     The cumsum is mean-centred per (b, k) stream: raw partial sums grow
     like t·E[log p] (≈ -t·log K), so at large T the f32 endpoint

@@ -182,6 +182,55 @@ def test_arc_scores_long_T_regression():
         np.testing.assert_allclose(got[b], ref_b, atol=5e-5)
 
 
+def _flattened_arc_scores(log_probs, start, end, label, kappa):
+    """The endpoint gather as it was: a zero row prepended to the
+    mean-centred cumsum, flattened to (B, (T+1)K), two 1-D gathers."""
+    B, T, K = log_probs.shape
+    lp = log_probs.astype(jnp.float32)
+    mu = jnp.mean(lp, axis=1, keepdims=True)
+    cum = jnp.cumsum(lp - mu, axis=1)
+    cum = jnp.concatenate([jnp.zeros_like(cum[:, :1]), cum], axis=1)
+    flat = cum.reshape(B, (T + 1) * K)
+    lab = label.reshape(B, -1).astype(jnp.int32)
+    hi = jnp.take_along_axis(flat, end.reshape(B, -1) * K + lab, axis=1)
+    lo = jnp.take_along_axis(flat, start.reshape(B, -1) * K + lab, axis=1)
+    span = (end - start).reshape(B, -1).astype(jnp.float32)
+    mu_lab = jnp.take_along_axis(mu[:, 0, :], lab, axis=1)
+    return (kappa * (hi - lo + span * mu_lab)).reshape(start.shape)
+
+
+@pytest.mark.parametrize("T", [512, 1024])
+@pytest.mark.parametrize("layout", ["arcs", "sausage"])
+def test_arc_scores_in_place_gather_matches_flattened(layout, T):
+    """The in-place endpoint gather picks the same cumsum elements as the
+    flattened formula: forward scores bitwise equal, JVP and VJP within
+    1e-6 relative, in arc layout (B, A) and sausage layout (B, S, W)."""
+    B, states = 3, 16
+    lat = make_lattice_batch(T, batch=B, num_frames=T, num_states=states,
+                             seg_len=4, n_alt=3)
+    idx = (lat.start_t, lat.end_t, lat.label)
+    if layout == "sausage":
+        idx = tuple(ref.gather_sausage_ref(x, lat.level_arcs, 0)
+                    for x in idx)
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(T), 3)
+    lp = jax.nn.log_softmax(jax.random.normal(k1, (B, T, states)), -1)
+    tangent = jax.random.normal(k2, lp.shape)
+    cotangent = jax.random.normal(k3, idx[0].shape)
+
+    def run(f):
+        score = lambda x: f(x, *idx, 0.5)                  # noqa: E731
+        y, dy = jax.jvp(score, (lp,), (tangent,))
+        _, vjp = jax.vjp(score, lp)
+        return y, dy, vjp(cotangent)[0]
+
+    y, dy, ct = run(ref.sausage_arc_scores_ref)
+    y0, dy0, ct0 = run(_flattened_arc_scores)
+    assert y.shape == idx[0].shape
+    np.testing.assert_array_equal(np.asarray(y), np.asarray(y0))
+    np.testing.assert_allclose(dy, dy0, rtol=1e-6)
+    np.testing.assert_allclose(ct, ct0, rtol=1e-6)
+
+
 @pytest.mark.parametrize("backend", BACKENDS)
 @pytest.mark.parametrize("accumulators", ["full", "loss_only"])
 def test_padded_arcs_get_zero_cotangent(backend, accumulators):

@@ -176,3 +176,31 @@ def test_nghf_sequence_step_compiles_for_v5e(chip, monkeypatch):
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes \
         < V5E_HBM_BYTES
+
+
+def test_arc_scores_gather_in_place_for_v5e(chip):
+    """``arc_scores`` with its JVP and VJP at the benchmark cell's CG
+    shape (16 x 512 frames, 6000 outputs, 384 arcs) gathers from the
+    cumsum in place: no relayout loop (``while`` over
+    ``dynamic-update-slice``) and no flat or zero-row padded copy of the
+    (16, 513, 6000) grid, which took 792 MB of temp memory."""
+    from repro.lattice_engine.common import arc_scores
+    from repro.losses.lattice import Lattice
+
+    B, T, A = 16, 512, 384
+    lp = _spec((B, T, K), jnp.float32, chip)
+    idx = _spec((B, A), jnp.int32, chip)
+
+    def fn(log_probs, start, end, label, tangent, cotangent):
+        lat = Lattice(start, end, label, *(None,) * 9)
+        score = lambda x: arc_scores(lat, x, 0.5)           # noqa: E731
+        y, dy = jax.jvp(score, (log_probs,), (tangent,))
+        _, vjp = jax.vjp(score, log_probs)
+        return y, dy, vjp(cotangent)[0]
+
+    compiled = jax.jit(fn).lower(
+        lp, idx, idx, idx, lp, _spec((B, A), jnp.float32, chip)).compile()
+    text = compiled.as_text()
+    assert " while(" not in text
+    assert "dynamic-update-slice(" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 600e6
