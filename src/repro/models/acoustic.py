@@ -33,6 +33,34 @@ def _fc_apply(p, x):
     return x @ p["w"] + p["b"]
 
 
+# Matmul precision of the TDNN's dots, by kind of layer: "hidden" for the
+# five spliced layers, "output" for the 1000 -> 6000 layer; None is the
+# ambient precision.  On a TPU v5e (PERF.md, Findings; 12 seeds) the
+# default one-pass bf16 makes the TDNN's NGHF MPE update on DAG lattices
+# depart from the float32 reference: loss gap up to 4.3e-3, another CG
+# candidate on 6 seeds.  HIGH (three bf16 passes) on the hidden layers
+# alone departs as far (up to 1.6e-3, 7 seeds), as does the output layer
+# alone; HIGH on all six agrees to 9e-6 and keeps the reference's
+# candidates but on one near tie.  JAX carries a dot's precision into its
+# JVP and transpose, so the curvature products and the backward pass run
+# at it too.
+TDNN_PRECISION = {"hidden": jax.lax.Precision.HIGH,
+                  "output": jax.lax.Precision.HIGH}
+
+
+def _tdnn_fc_apply(p, x, precision):
+    """``_fc_apply`` at ``precision``.  A stated precision runs over the
+    (B*T) rows of x: at (B, T, 6000) and HIGH the TPU compiler overruns its
+    64 MiB scoped-VMEM limit on the output layer's product cotangent
+    (98.9 MiB at 32 x 512 frames); over rows it does not.  T a multiple of
+    8 makes both reshapes free on the (8, 128) tiles."""
+    if precision is None:
+        return _fc_apply(p, x)
+    B, T, D = x.shape
+    y = jnp.matmul(x.reshape(B * T, D), p["w"], precision=precision)
+    return y.reshape(B, T, -1) + p["b"]
+
+
 # ---------------------------------------------------------------------------
 # init
 # ---------------------------------------------------------------------------
@@ -118,10 +146,15 @@ def forward(cfg, params, feats):
             x = layer(cfg, params[f"rec{i}"], x)
         for i in range(cfg.num_ff_layers):
             x = _act(cfg.activation, _fc_apply(params[f"ff{i}"], x))
-    else:
-        for i, ctx in enumerate(cfg.tdnn_contexts):
-            x = _act(cfg.activation, _fc_apply(params[f"tdnn{i}"], _splice(x, ctx)))
-    return _fc_apply(params["out"], x)
+        return _fc_apply(params["out"], x)
+    for i, ctx in enumerate(cfg.tdnn_contexts):
+        with jax.named_scope("tdnn_splice"):
+            x_ctx = _splice(x, ctx)
+        with jax.named_scope("tdnn_hidden"):
+            x = _act(cfg.activation, _tdnn_fc_apply(
+                params[f"tdnn{i}"], x_ctx, TDNN_PRECISION["hidden"]))
+    with jax.named_scope("output_layer"):
+        return _tdnn_fc_apply(params["out"], x, TDNN_PRECISION["output"])
 
 
 # ---------------------------------------------------------------------------

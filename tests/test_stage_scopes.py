@@ -40,7 +40,20 @@ def _step_args():
 
 
 def _compiled_text(fn, args):
-    return jax.jit(fn).lower(*args).compile().as_text()
+    # JAX's persistent compilation cache keys a program on its HLO without
+    # the op_name metadata: with the cache on (an earlier test in the
+    # process may turn it on), the program compiled without scopes would
+    # load the scoped one's executable.  So compile afresh.
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        return jax.jit(fn).lower(*args).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        cc.reset_cache()
 
 
 @pytest.fixture(scope="module")

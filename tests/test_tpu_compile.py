@@ -178,6 +178,65 @@ def test_nghf_sequence_step_compiles_for_v5e(chip, monkeypatch):
         < V5E_HBM_BYTES
 
 
+# the benchmark cell tdnn-asr.nghf-mpe.dag: gradient batch 128 x 512
+# frames, CG batch 32 x 512, general-DAG lattices padded to the envelope
+# (arcs, fan, levels, width) = (528, 9, 128, 9)
+TDNN_CELL = dict(grad=128, cg=32, T=512, A=528, P=9, L=128, W=9)
+
+
+def _dag_batch(chip, B, acfg):
+    c = TDNN_CELL
+    A, T = c["A"], c["T"]
+    i32 = lambda *shp: _spec(shp, jnp.int32, chip)        # noqa: E731
+    f32 = lambda *shp: _spec(shp, jnp.float32, chip)      # noqa: E731
+    flag = _spec((B, A), jnp.bool_, chip)
+    from repro.losses.lattice import Lattice
+    lattice = Lattice(
+        start_t=i32(B, A), end_t=i32(B, A), label=i32(B, A), lm=f32(B, A),
+        corr=f32(B, A), preds=i32(B, A, c["P"]), succs=i32(B, A, c["P"]),
+        is_start=flag, is_final=flag, arc_mask=flag, ref_states=i32(B, T),
+        num_ref_units=f32(B), level_arcs=i32(B, c["L"], c["W"]))
+    return {"feats": f32(B, T, acfg.input_dim), "labels": i32(B, T),
+            "lattice": lattice}
+
+
+def test_tdnn_dag_sequence_step_compiles_for_v5e(chip, monkeypatch,
+                                                 record_property):
+    """The full-width ``tdnn-asr`` MPE update (80 -> five spliced
+    1000-wide layers -> 6000 outputs, the model's stated matmul precision)
+    on DAG lattices at the benchmark cell's shapes, ``backend="auto"``
+    (the levelized engine under jit), as the cell jits it: fits one
+    chip's memory."""
+    from repro.configs.acoustic import get_acoustic_config
+    from repro.core.optim import config_for
+    from repro.launch import steps
+    from repro.models import acoustic
+
+    # jit_train_step asks the default backend (CPU here) whether to pass
+    # the TPU compiler options; the described chip is a TPU
+    monkeypatch.setattr(dispatch, "compiled_backend", lambda: True)
+    acfg = get_acoustic_config("tdnn-asr")
+    ocfg = config_for("nghf", cg_iters=8, ng_iters=4, lam=1.0,
+                      preconditioner="share_counts")
+    place = lambda t: jax.tree.map(                         # noqa: E731
+        lambda x: _spec(x.shape, x.dtype, chip), t)
+    params = place(jax.eval_shape(
+        lambda: acoustic.init_params(acfg, jax.random.PRNGKey(0))))
+    fn, opt = steps.build_sequence_step(
+        acfg, ocfg, loss="mpe", kappa=0.5, backend="auto",
+        share_counts=acoustic.share_counts(acfg, params))
+    state = place(jax.eval_shape(opt.init, params))
+    grad_batch = _dag_batch(chip, TDNN_CELL["grad"], acfg)
+    cg_batch = _dag_batch(chip, TDNN_CELL["cg"], acfg)
+    compiled = steps.jit_train_step(fn).lower(
+        params, state, grad_batch, cg_batch).compile()
+    assert "tpu_custom_call" not in compiled.as_text()
+    mem = compiled.memory_analysis()
+    record_property("temp_size_in_bytes", mem.temp_size_in_bytes)
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes \
+        < V5E_HBM_BYTES
+
+
 def test_arc_scores_gather_in_place_for_v5e(chip):
     """``arc_scores`` with its JVP and VJP at the benchmark cell's CG
     shape (16 x 512 frames, 6000 outputs, 384 arcs) gathers from the
