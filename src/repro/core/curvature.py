@@ -13,10 +13,18 @@ product  F v = Jᵀ (F^ (J v))  are computed matrix-free:
     of the linearized JVP (``jax.linear_transpose``), reusing the stored
     forward residuals.
 
-``linearize`` is called ONCE per CG stage (the parameters and CG batch are
-fixed across CG iterations), so each CG iteration costs one JVP + one
-transposed JVP + (optionally) one candidate-evaluation forward — matching
-the cost profile in paper Table 1.
+The parameters and the CG batch are fixed across a CG stage, and so are
+the primal logits.  ``make_curvature_ops`` therefore builds the logit-space
+factors ONCE per CG stage where the LossSpec has a prepared form
+(``curvature_factors``: the lattice losses run their lattice gradients
+there, scope ``curvature_product/curvature_factor``), and each product
+applies them to its ``J v`` as elementwise work.  Under ``linearize``
+(also called once per CG stage) each CG iteration then costs one JVP +
+one transposed JVP + the factor apply + (optionally) one candidate-
+evaluation forward — matching the cost profile in paper Table 1; under
+``rematvp`` each product recomputes the forward it differentiates.  A
+spec without the prepared form (CE, the chunked LM loss) computes its
+factor inside every product, at that product's primal output.
 
 Numerical stability (paper Sec. 4.2): when ‖θ‖₂ ≫ ‖v‖₂ the directional
 derivative loses float precision and the quadratic form can evaluate
@@ -136,25 +144,36 @@ def make_curvature_ops(forward_fn, loss_spec, params, batch, *,
     def f(p):
         return forward_fn(p, curv_batch)[0]
 
+    logits = None
     if mode == "linearize":
         with jax.named_scope("curvature_product"):
             logits, jvp_fn = jax.linearize(f, params)
         vjp_fn = jax.linear_transpose(jvp_fn, params)
+
+    prepare = getattr(loss_spec, "curvature_factors", None)
+    if prepare is not None:
+        # built here, before any solve, so no CG loop body holds the build
+        with jax.named_scope("curvature_product"), \
+                jax.named_scope("curvature_factor"):
+            gn_apply, fisher_apply = prepare(
+                f(params) if logits is None else logits, curv_batch)
+
+        def gn_factor(out, u):
+            return gn_apply(u)
+
+        def fisher_factor(out, u):
+            return fisher_apply(u)
     else:
-        logits = None
+        def gn_factor(out, u):
+            return loss_spec.gn_vp(out, curv_batch, u)
 
-        def jvp_fn(v):                           # noqa: ANN001
-            _, jv = jax.jvp(f, (params,), (v,))
-            return jv
-
-        def vjp_fn(cot):
-            _, pullback = jax.vjp(f, params)
-            return pullback(cot)
+        def fisher_factor(out, u):
+            return loss_spec.fisher_vp(out, curv_batch, u)
 
     if theta_norm is None:
         theta_norm = tm.norm(params)
 
-    def _product(factor_vp, v):
+    def _product(factor, v):
         with jax.named_scope("curvature_product"):
             if stabilize:
                 v_norm = jnp.maximum(tm.norm(v), 1e-30)
@@ -167,22 +186,19 @@ def make_curvature_ops(forward_fn, loss_spec, params, batch, *,
             # f32 master params)
             v_in = tm.cast_like(v_in, params)
             if mode == "linearize":
-                out_primal = logits
-                jv = jvp_fn(v_in)
-                hu = factor_vp(out_primal, curv_batch, jv)
-                (out,) = vjp_fn(hu)
+                (out,) = vjp_fn(factor(logits, jvp_fn(v_in)))
             else:
                 out_primal, jv = jax.jvp(f, (params,), (v_in,))
-                hu = factor_vp(out_primal, curv_batch, jv)
+                hu = factor(out_primal, jv)
                 _, pullback = jax.vjp(f, params)
                 (out,) = pullback(hu)
             return tm.scale(out, 1.0 / s) if stabilize else out
 
     def gnvp(v):
-        return _product(loss_spec.gn_vp, v)
+        return _product(gn_factor, v)
 
     def fvp(v):
-        return _product(loss_spec.fisher_vp, v)
+        return _product(fisher_factor, v)
 
     # pass the kwarg only to LossSpecs that declare it, so specs with the
     # pre-accumulators signature keep working under the default

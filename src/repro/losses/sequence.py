@@ -8,6 +8,18 @@ A ``LossSpec`` packages everything NGHF needs from a training criterion
     gn_vp(logits, batch, u)       -> per-frame GN factor product  H^ u
     fisher_vp(logits, batch, u)   -> per-frame empirical-Fisher product F^ u
 
+The lattice losses also give the prepared form
+
+    curvature_factors(logits, batch) -> (u -> H^ u, u -> F^ u)
+
+which runs the lattice gradients G once at ``logits`` and closes over
+them, so that applying a factor is elementwise work over (B,T,K).
+``core.curvature.make_curvature_ops`` builds it once per CG stage (the
+parameters and the CG batch are fixed there, only u changes); their
+``gn_vp``/``fisher_vp`` are thin wrappers over it.  A spec without it
+(CE, the chunked LM loss: no lattice recursion to save) is called
+per product.
+
 ``value``'s ``accumulators`` selects the lattice-engine statistics mode:
 ``"loss_only"`` computes only what the loss value needs (no backward
 recursion; on the Pallas backend one fused forward kernel) — this is what
@@ -97,6 +109,35 @@ class CELoss:
 
 
 # ---------------------------------------------------------------------------
+# Prepared curvature factors of the lattice losses
+# ---------------------------------------------------------------------------
+
+def _matching_apply(lat: Lattice, g, diag_w, rank1_w):
+    """u -> diag_w (y ⊙ u) + rank1_w G (yᵀu), y the one-hot reference
+    states.  Edge-padded frames are masked out of y: the loss has zero
+    dependence on them, so the curvature must not add PSD mass there
+    (padding-dependent GN shifts the CG direction)."""
+    mask = lattice_frame_mask(lat)[..., None]
+
+    def apply(u):
+        y = jax.nn.one_hot(lat.ref_states, u.shape[-1],
+                           dtype=jnp.float32) * mask
+        yu = jnp.sum(y * u, -1, keepdims=True)
+        return diag_w * (y * u) + rank1_w * g * yu
+
+    return apply
+
+
+def _fisher_apply(g, S):
+    """u -> S G (Gᵀu) per frame (Eq. 19), S the number of loss atoms."""
+    def apply(u):
+        gu = jnp.sum(g * u, -1, keepdims=True)
+        return S * g * gu
+
+    return apply
+
+
+# ---------------------------------------------------------------------------
 # Lattice MMI (Eqn. 2)
 # ---------------------------------------------------------------------------
 
@@ -146,28 +187,27 @@ class MMILoss:
         return jax.grad(lambda lg: self.value(lg, batch)[0])(
             logits.astype(jnp.float32))
 
-    def gn_vp(self, logits, batch, u):
-        """MMI matching-loss GN factor, matrix-free via the denominator
+    def curvature_factors(self, logits, batch):
+        """(u -> H^u, u -> F^u) at ``logits``, both from one G_mmi.
+
+        GN: the MMI matching-loss factor, matrix-free via the denominator
         occupancy: H^u ≈ κ²w(γ_den ⊙ u - γ_den(γ_denᵀu)) computed with two
         VJP-free softmax-style contractions on the ML occupancy is not
         available in closed form here, so we use the exact Gauss-Newton of
         the *numerator* matching part plus the rank-1 denominator term
-        derived from logit_grad (same structure as the MPE factor)."""
-        lat: Lattice = batch["lattice"]
-        w = self.kappa ** 2 / self._frames(lat)
-        y = jax.nn.one_hot(lat.ref_states, logits.shape[-1],
-                           dtype=jnp.float32) \
-            * lattice_frame_mask(lat)[..., None]
-        g = self.logit_grad(logits, batch)
-        yu = jnp.sum(y * u, -1, keepdims=True)
-        return w * (y * u) + self.kappa * g * yu
-
-    def fisher_vp(self, logits, batch, u):
+        derived from logit_grad (same structure as the MPE factor).
+        Fisher: Eq. 19."""
         lat: Lattice = batch["lattice"]
         g = self.logit_grad(logits, batch)
         S = self._frames(lat)
-        gu = jnp.sum(g * u, -1, keepdims=True)
-        return S * g * gu
+        return (_matching_apply(lat, g, self.kappa ** 2 / S, self.kappa),
+                _fisher_apply(g, S))
+
+    def gn_vp(self, logits, batch, u):
+        return self.curvature_factors(logits, batch)[0](u)
+
+    def fisher_vp(self, logits, batch, u):
+        return self.curvature_factors(logits, batch)[1](u)
 
 
 # ---------------------------------------------------------------------------
@@ -199,25 +239,26 @@ class MPELoss:
         return jax.grad(lambda lg: self.value(lg, batch)[0])(
             logits.astype(jnp.float32))
 
-    def gn_vp(self, logits, batch, u):
-        """Eqn. 11 via the Sec. 3.4 Hadamard form:
+    def _gn_factor(self, logits, batch):
+        """u -> H^u, Eqn. 11 via the Sec. 3.4 Hadamard form:
         H^u = κ² w (γ_ml ⊙ u) + κ G (γ_mlᵀ u), G = -κ w γ^MBR."""
         lat: Lattice = batch["lattice"]
         B = logits.shape[0]
         w = (1.0 / (B * jnp.maximum(lat.num_ref_units, 1.0)))[:, None, None]
-        # mask edge-padded frames out of the matching term: the loss has
-        # zero dependence on them, so the curvature must not add PSD mass
-        # there (padding-dependent GN shifts the CG direction)
-        y = jax.nn.one_hot(lat.ref_states, logits.shape[-1],
-                           dtype=jnp.float32) \
-            * lattice_frame_mask(lat)[..., None]
-        g = self.logit_grad(logits, batch)
-        yu = jnp.sum(y * u, -1, keepdims=True)
-        return (self.kappa ** 2) * w * (y * u) + self.kappa * g * yu
+        return _matching_apply(lat, self.logit_grad(logits, batch),
+                               (self.kappa ** 2) * w, self.kappa)
+
+    def curvature_factors(self, logits, batch):
+        """(u -> H^u, u -> F^u) at ``logits``: G_mpe and G_mmi once each.
+        The Fisher comes from the *MMI* loss (Sec. 5.2), regardless of
+        training criterion — NGHF's MPE/MMI interpolation."""
+        return (self._gn_factor(logits, batch),
+                self._mmi.curvature_factors(logits, batch)[1])
+
+    def gn_vp(self, logits, batch, u):
+        return self._gn_factor(logits, batch)(u)
 
     def fisher_vp(self, logits, batch, u):
-        """Fisher from the *MMI* loss (Sec. 5.2), regardless of training
-        criterion — NGHF's MPE/MMI interpolation."""
         return self._mmi.fisher_vp(logits, batch, u)
 
 

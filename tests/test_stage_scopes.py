@@ -2,9 +2,9 @@
 the trainer loop's host spans.
 
 The stages are ``jax.named_scope``s where the work is traced
-(``grad_stage``, ``curvature_product``, ``candidate_eval``, ``cg_solve``,
-``lattice_stats``); a profiler trace attributes each device operation to
-them through its HLO ``op_name``.  They change metadata only: the
+(``grad_stage``, ``curvature_product`` with its ``curvature_factor``,
+``candidate_eval``, ``cg_solve``, ``lattice_stats``); a profiler trace
+attributes each device operation to them through its HLO ``op_name``.  They change metadata only: the
 compiled program keeps every instruction and fusion."""
 import contextlib
 import glob
@@ -21,8 +21,8 @@ from repro.launch.steps import build_sequence_step
 from repro.models import acoustic
 
 CFG = LSTM.smoke().replace(hidden_dim=16, num_outputs=12)
-STAGES = ("grad_stage", "curvature_product", "candidate_eval", "cg_solve",
-          "lattice_stats")
+STAGES = ("grad_stage", "curvature_product", "curvature_factor",
+          "candidate_eval", "cg_solve", "lattice_stats")
 SPANS = ("train.make_batch", "train.update", "train.read_metrics",
          "train.checkpoint", "train.rebuild")
 
@@ -79,8 +79,14 @@ def test_sequence_step_names_every_stage(step):
     # and transposed
     assert any("grad_stage/" in n and "transpose(jvp(lattice_stats))" in n
                for n in names)
-    assert any(_holds(n, "cg_solve") and _holds(n, "curvature_product")
-               and _holds(n, "lattice_stats") for n in names)
+    # the products' lattice gradients run once, where their factor is
+    # built before the solves, and in no solve's loop body
+    assert any(_holds(n, "curvature_factor")
+               and _holds(n, "curvature_product")
+               and _holds(n, "lattice_stats")
+               and not _holds(n, "cg_solve") for n in names)
+    assert not any(_holds(n, "cg_solve") and _holds(n, "curvature_product")
+                   and _holds(n, "lattice_stats") for n in names)
     # the zero update's evaluation runs outside the solve
     assert any(_holds(n, "candidate_eval") and not _holds(n, "cg_solve")
                for n in names)
